@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "src"))
 
 from benchmarks import paper_tables as pt  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def _timed(fn):
@@ -338,58 +339,87 @@ def bench_serving_routing():
                   f"step_ms={[round(y, 2) for y in prof.step_curve.ys]}")
 
 
+def mesh_profile_record() -> dict:
+    """Build a Replica on a (1, 4) serving mesh over the first four devices
+    — its decode steps run the split-S distributed flash-decode with the
+    per-lane index vector — time ``measure_step_curve`` and decode one
+    request through the batched loop.  On a TPU the replica serves
+    qwen3-4b at its published widths (the Pallas kernels need
+    lane-aligned heads) with the depth cut to 9 of 36 layers, as
+    ``chip_smoke.py --four-chips`` runs it; elsewhere a float32 granite-8b
+    smoke config.  The record names the platform and the config it ran."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import auto_mesh
+    from repro.launch.serve import serving_config
+    from repro.models import model as M
+    from repro.serving.engine import Replica, Request, measure_step_curve
+
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        cfg = serving_config("qwen3-4b", smoke=False).replace(num_layers=9)
+        capacity = 1024
+    else:
+        cfg = get_smoke_config("granite-8b").replace(
+            param_dtype=jnp.float32, dtype=jnp.float32)
+        capacity = 128
+    mesh = auto_mesh((1, 4), ("data", "model"), devices=jax.devices()[:4])
+    params = M.init_params(jax.random.PRNGKey(0), cfg,
+                           NamedSharding(mesh, PartitionSpec()))
+    rep = Replica("mesh0", cfg, params, slots=4, capacity=capacity,
+                  serving_mesh=mesh)
+    occs, step_ms, chunk_ms = measure_step_curve(rep, steps_per_point=4)
+    toks = rep.generate(Request(0, np.arange(2, 10, dtype=np.int32), 4, 1e9))
+    rep.stop()
+    return {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "config": f"{cfg.name} ({cfg.num_layers} layers, d_model "
+                  f"{cfg.d_model})",
+        "mesh": {k: int(v) for k, v in mesh.shape.items()},
+        "occupancy": occs,
+        "step_ms_by_occupancy": [round(y, 3) for y in step_ms],
+        "prefill_chunk_ms": round(chunk_ms, 3),
+        "tokens_decoded": int(len(toks)),
+    }
+
+
 def bench_serving_mesh_step_curve():
-    """Lane-occupancy step curve of a SHARDED replica: a subprocess with
-    fake host devices builds a Replica on a (1, 4) serving mesh — its
-    decode steps run the split-S distributed flash-decode with the
-    per-lane index vector — and times ``measure_step_curve``, so
-    BENCH_serving.json tracks the distributed step cadence alongside the
-    single-device one.  A subprocess because the host device count must
-    be pinned via XLA_FLAGS before jax initializes (the parent already
-    holds a default client)."""
+    """Lane-occupancy step curve of a SHARDED replica
+    (``mesh_profile_record``), so BENCH_serving.json tracks the
+    distributed step cadence alongside the single-device one.  On a host
+    with four devices it runs in this process, on them.  Otherwise it runs
+    in a child with four fake CPU devices (the device count must be pinned
+    via XLA_FLAGS before jax initializes); that child touches no
+    accelerator, and its record says ``cpu``."""
     import subprocess
 
-    code = """
-import json
-import jax, jax.numpy as jnp
-from repro.configs import get_smoke_config
-from repro.models import model as M
-from repro.serving.engine import Replica, Request, measure_step_curve
-import numpy as np
+    import jax
 
-cfg = get_smoke_config("granite-8b").replace(param_dtype=jnp.float32,
-                                             dtype=jnp.float32)
-params = M.init_model(jax.random.PRNGKey(0), cfg)
-mesh = jax.make_mesh((1, 4), ("data", "model"))
-rep = Replica("mesh0", cfg, params, slots=4, capacity=128,
-              serving_mesh=mesh)
-occs, step_ms, chunk_ms = measure_step_curve(rep, steps_per_point=4)
-# and one end-to-end request through the batched loop on the mesh
-toks = rep.generate(Request(0, np.arange(2, 10, dtype=np.int32), 4, 1e9))
-rep.stop()
-print(json.dumps({
-    "mesh": {k: int(v) for k, v in mesh.shape.items()},
-    "occupancy": occs,
-    "step_ms_by_occupancy": [round(y, 3) for y in step_ms],
-    "prefill_chunk_ms": round(chunk_ms, 3),
-    "tokens_decoded": int(len(toks)),
-}))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4").strip()
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
-    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
-                         if env.get("PYTHONPATH") else src)
-    env.setdefault("REPRO_KERNEL_IMPL", "jnp")
-    # fake host devices exist on the CPU backend only: without this, a
-    # host with an accelerator would initialize that backend and the
-    # (1, 4) mesh would not have 4 devices to build from
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=900)
-    assert out.returncode == 0, out.stderr[-3000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    if len(jax.devices()) >= 4:
+        rec = mesh_profile_record()
+    else:
+        root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                            + " --xla_force_host_platform_device_count=4"
+                            ).strip()
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(root, "src"), root]
+            + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+        env.setdefault("REPRO_KERNEL_IMPL", "jnp")
+        env["JAX_PLATFORMS"] = "cpu"
+        code = ("import json; from benchmarks.run import mesh_profile_record;"
+                " print(json.dumps(mesh_profile_record()))")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, env=env,
+                             timeout=900)
+        assert out.returncode == 0, out.stderr[-3000:]
+        rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["tokens_decoded"] == 4, rec
     SERVING_METRICS["mesh_profile"] = rec
     rows = [{"occupancy": o, "step_ms": m}
@@ -843,6 +873,7 @@ def main() -> None:
                         "BENCH_serving.json"),
                     help="where to write the serving metrics JSON")
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
 
     serving = [("bench_serving_throughput", bench_serving_throughput),
                ("bench_serving_recurrent_throughput",

@@ -4,14 +4,21 @@ TPU-native design:
   * grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is
     ``arbitrary`` (sequential) so the (m, l, acc) VMEM scratch accumulators
     carry across kv steps — the canonical TPU flash pattern.
-  * BlockSpecs stream (block_q x head_dim) / (block_k x head_dim) tiles
-    HBM->VMEM; head_dim and block sizes are multiples of 128 at production
-    shapes so the MXU matmuls are hardware-aligned.
+  * q/k/v are viewed as ``(B, S, H*D)`` (a reshape of freshly computed
+    activations, which XLA lays out for the kernel) and BlockSpecs stream
+    ``(block, D)`` tiles with the head index in the last block position —
+    the tiling the TPU lowering accepts (last two block dims divisible by
+    8 and 128, or equal to the array's).  A head width that is not a
+    multiple of 128 (64 in minicpm-2b and musicgen-medium) is zero-padded
+    up to one first: the pad changes no score and no output column, at
+    twice the kernel's FLOPs for D=64.  Block sizes are 128 at prefill
+    lengths; a shorter sequence is one whole block.
   * GQA is free: the k/v index_map folds q-head -> kv-head, so kv tiles are
     fetched once per kv-head group.
   * causal / sliding-window / length masking via in-kernel iota compare.
 
-Validated on CPU with ``interpret=True`` against ``ref.mha_reference``.
+Validated on CPU with ``interpret=True`` against ``ref.mha_reference`` and
+compiled for v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -21,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import compat
 
 NEG_INF = -1e30
 
@@ -38,9 +43,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, d)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)                   # (bq, d)
+    k = k_ref[0].astype(jnp.float32)                   # (bk, d)
+    v = v_ref[0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -59,7 +64,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     m_prev = m_scr[...]                                # (bq, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                             # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)       # (bq, bk)
     corr = jnp.exp(m_prev - m_new)                     # (bq, 1)
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
@@ -69,7 +74,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == num_kv_blocks - 1)
     def _done():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None, softcap=0.0,
@@ -88,7 +93,15 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None, softcap=0.0,
     if t_pad:
         k = jnp.pad(k, ((0, 0), (0, t_pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, t_pad), (0, 0), (0, 0)))
+    d_pad = -d % 128
+    if d_pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
+                   for x in (q, k, v))
+    dp = d + d_pad
     nq, nk = (s + s_pad) // block_q, (t + t_pad) // block_k
+    q = q.reshape(b, s + s_pad, hq * dp)
+    k = k.reshape(b, t + t_pad, hkv * dp)
+    v = v.reshape(b, t + t_pad, hkv * dp)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
@@ -99,21 +112,22 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None, softcap=0.0,
         kernel,
         grid=(b, hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda b_, h, qi, ki: (b_, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h, qi, ki: (b_, ki, h // rep, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h, qi, ki: (b_, ki, h // rep, 0)),
+            pl.BlockSpec((1, block_q, dp), lambda b_, h, qi, ki: (b_, qi, h)),
+            pl.BlockSpec((1, block_k, dp), lambda b_, h, qi, ki: (b_, ki, h // rep)),
+            pl.BlockSpec((1, block_k, dp), lambda b_, h, qi, ki: (b_, ki, h // rep)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b_, h, qi, ki: (b_, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s + s_pad, hq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, dp),
+                               lambda b_, h, qi, ki: (b_, qi, h)),
+        out_shape=jax.ShapeDtypeStruct((b, s + s_pad, hq * dp), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((block_q, dp), jnp.float32),  # output accumulator
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
-    return out[:, :s]
+    return out.reshape(b, s + s_pad, hq, dp)[:, :s, :, :d]

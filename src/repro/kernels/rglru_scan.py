@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _rglru_kernel(a_ref, b_ref, y_ref, h_scr, *, chunk):
     ci = pl.program_id(2)   # chunk dim is innermost so h carries per d-block
@@ -83,7 +81,7 @@ def rglru(x, log_a, gate_x, *, chunk=256, block_d=None, interpret=False):
                                lambda b_, d_, c_: (b_, c_, d_)),
         out_shape=jax.ShapeDtypeStruct((bsz, s + pad, d + dpad), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

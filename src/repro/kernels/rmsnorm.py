@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)                 # (rows, D)
@@ -52,7 +50,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

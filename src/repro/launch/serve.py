@@ -5,6 +5,11 @@ pre-evaluation -> two-level DDS routing -> SLO accounting.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --smoke \
       --requests 16 --policy DDS
 
+``--smoke`` (the default) serves the architecture's reduced smoke config;
+``--no-smoke`` serves its published config with weights held in the
+compute dtype (bf16: qwen3-4b is 8.0 GB) — a chip's worth of model.
+Replicas are spread over the host's local devices, one device each.
+
 Per-request sampling rides on the request: ``--temperature/--top-k/--top-p``
 set the knobs for every generated request (0 temperature = greedy), and
 ``--sample-seed`` fixes the PRNG root so a rerun reproduces the exact token
@@ -40,16 +45,41 @@ from __future__ import annotations
 import argparse
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import List
+from typing import List, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.policies import make_policy
+from repro.kernels.ops import kernel_impl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving.engine import Replica, Request, ServingFleet
 from repro.serving.overload import PRIORITIES, BrownoutConfig
+
+
+def serving_config(arch: str, smoke: bool = True):
+    """The config a fleet serves: the smoke config as is, or the published
+    config with params held in the compute dtype (serving keeps no f32
+    master copy)."""
+    if smoke:
+        return get_smoke_config(arch)
+    cfg = get_config(arch)
+    return cfg.replace(param_dtype=cfg.dtype)
+
+
+def replica_params(cfg, replicas: int, seed: int = 0) -> List:
+    """Random params for ``replicas`` replicas, replica ``i`` on local
+    device ``i % n``: initialised once under ``jit`` on the first device
+    and copied to each further device that needs them."""
+    devices = jax.local_devices()
+    first = model_lib.init_params(jax.random.PRNGKey(seed), cfg,
+                                  jax.sharding.SingleDeviceSharding(devices[0]))
+    placed = {0: first}
+    for i in range(1, min(replicas, len(devices))):
+        placed[i] = jax.device_put(first, devices[i])
+    return [placed[i % len(devices)] for i in range(replicas)]
 
 
 def build_fleet(cfg, policy_name: str, replicas: int = 2,
@@ -60,14 +90,16 @@ def build_fleet(cfg, policy_name: str, replicas: int = 2,
                 brownout: bool = False,
                 paged: bool = False, page_size: int = 16,
                 num_pages: int = 0,
-                prefix_cache: bool = False) -> ServingFleet:
-    key = jax.random.PRNGKey(0)
-    params = model_lib.init_model(key, cfg)
+                prefix_cache: bool = False,
+                params: Optional[List] = None) -> ServingFleet:
+    """A DDS ``ServingFleet`` of ``replicas`` warm replicas; ``params``
+    (one tree per replica) defaults to ``replica_params``."""
+    params = params if params is not None else replica_params(cfg, replicas)
     fleet = ServingFleet(make_policy(policy_name), source="replica0",
                          coordinator="replica1" if replicas > 1 else "replica0",
                          admission_margin=admission_margin)
     for i in range(replicas):
-        rep = Replica(f"replica{i}", cfg, params, slots=slots,
+        rep = Replica(f"replica{i}", cfg, params[i], slots=slots,
                       capacity=capacity,
                       prefill_chunk_tokens=prefill_chunk_tokens,
                       step_slo_ms=step_slo_ms,
@@ -79,17 +111,21 @@ def build_fleet(cfg, policy_name: str, replicas: int = 2,
         paging = (f"paged KV ({rep.num_pages} pages x {rep.page_size} tok"
                   f"{', prefix cache' if prefix_cache else ''})"
                   if paged else "ring KV")
-        print(f"replica{i}: warmup (compile) {rep.warmup_s:.2f}s — "
+        print(f"replica{i} on {rep.device}: warmup (compile) "
+              f"{rep.warmup_s:.2f}s — "
               f"cold-start paid up front; chunked prefill "
               f"{'on' if rep.prefill_caps['supported'] else 'off'} "
               f"(budget ceiling {rep.prefill_chunk_tokens} tokens); {paging}")
     return fleet
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced smoke config (--no-smoke: the "
+                         "published config, bf16 weights)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -148,11 +184,20 @@ def main():
                     help="cross-request prefix reuse: prompts sharing "
                          "full cached blocks skip their prefill "
                          "(global-attention stacks only; implies --paged)")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
     if args.prefix_cache:
         args.paged = True
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    cache_dir = enable_compile_cache()
+    cfg = serving_config(args.arch, args.smoke)
+    dev = jax.devices()[0]
+    print(f"{cfg.name}: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}; kernels={kernel_impl()}; "
+          f"compile cache {cache_dir}")
     fleet = build_fleet(cfg, args.policy, replicas=args.replicas,
                         prefill_chunk_tokens=args.prefill_chunk_tokens,
                         step_slo_ms=args.step_slo_ms,
@@ -209,6 +254,10 @@ def main():
               f"fleet_failovers={fleet.failovers} lost={fleet.lost} "
               f"dead={fleet.dead}")
     fleet.stop()
+    if outcomes["lost"]:
+        # shed / rejected are overload control's accounted answers; a
+        # lost request is a failure the run must not report as success
+        raise SystemExit(f"{outcomes['lost']} request(s) lost")
 
 
 if __name__ == "__main__":

@@ -118,42 +118,6 @@ def self_attention(
     return y
 
 
-def decode_self_attention(
-    params,
-    x,                      # (B, 1, d_model)
-    k_cache,                # (B, S_max, Hkv, hd)
-    v_cache,
-    cache_index,            # scalar or (B,) int32: per-lane current length
-    cfg: ModelConfig,
-    attn_kind: str = GLOBAL,
-):
-    """Single-token decode with KV-cache update.
-
-    ``cache_index`` may be per-lane ``(B,)``: every lane inserts its new KV
-    at its own position and masks against its own length (continuous
-    batching — lanes at different depths decode in one call)."""
-    from repro.kernels import ops
-
-    b = x.shape[0]
-    idx = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (b,))
-    positions = idx[:, None]
-    q, k, v = _project_qkv(params, x, cfg, positions, attn_kind)
-    # insert each lane's new kv at that lane's cache_index
-    lanes = jnp.arange(b)
-    k_cache = k_cache.at[lanes, idx].set(k[:, 0].astype(k_cache.dtype))
-    v_cache = v_cache.at[lanes, idx].set(v[:, 0].astype(v_cache.dtype))
-    window = _window_for(cfg, attn_kind)
-    out = ops.decode_attention(
-        q, k_cache, v_cache,
-        cache_len=idx + 1,
-        window=window,
-        scale=cfg.attn_scale or cfg.resolved_head_dim ** -0.5,
-        softcap=cfg.logit_softcap,
-    )
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
-    return y, (k_cache, v_cache)
-
-
 # ------------------------------------------------------------------ cross-attn
 def cross_attention(
     params,

@@ -197,12 +197,9 @@ def apply_block_decode(p, x, cache, cfg: ModelConfig, kind: str, attn_kind: str,
             v_cache = cache["v"].at[lanes, slots].set(
                 v[:, 0].astype(cache["v"].dtype))
             pos = cache["pos"].at[lanes, slots].set(idx)       # (B, n)
-            valid = pos >= 0
-            if window > 0:
-                valid &= pos > idx[:, None] - window
-            out = ref.decode_mha_masked(
-                q, k_cache, v_cache, valid_mask=valid, scale=scale,
-                softcap=cfg.logit_softcap)
+            out = ops.decode_attention(
+                q, k_cache, v_cache, pos, cache_len=idx + 1, window=window,
+                scale=scale, softcap=cfg.logit_softcap)
         y = jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"].astype(x.dtype))
         x = x + y
         new_cache = {"k": k_cache, "v": v_cache, "pos": pos}

@@ -19,7 +19,10 @@ def dense_init(key, shape, in_axis: int = 0, dtype=jnp.float32):
     """Truncated-normal fan-in init (maxtext-style 1/sqrt(fan_in))."""
     fan_in = shape[in_axis]
     std = 1.0 / np.sqrt(fan_in)
-    return std * jax.random.truncated_normal(key, -2.0, 2.0, shape, dtype=dtype)
+    # ``std`` is a float64 numpy scalar: without the cast it would promote
+    # a bf16 draw to f32
+    return (std * jax.random.truncated_normal(key, -2.0, 2.0, shape,
+                                              dtype=dtype)).astype(dtype)
 
 
 def embed_init(key, shape, dtype=jnp.float32):

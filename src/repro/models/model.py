@@ -26,20 +26,22 @@ from repro.models import layers as lyr
 
 # ------------------------------------------------------------------------ init
 def init_model(key, cfg: ModelConfig) -> Dict[str, Any]:
+    """Random parameters.  Layer ``i`` draws from ``split(key)[i]``; each
+    period slot is initialised as one ``vmap`` over its layers' keys, so
+    the stacked leaves are built directly (no per-layer copies to stack,
+    and one traced layer per slot under ``jit``)."""
     cfg.validate()
     kinds = list(zip(cfg.layer_kinds(), cfg.attn_kinds()))
     keys = jax.random.split(key, cfg.num_layers + 2)
-    layer_params = [
-        blk.init_block(keys[i], cfg, kinds[i][0], kinds[i][1])
-        for i in range(cfg.num_layers)
-    ]
     p_len, reps = cfg.pattern_period, cfg.num_periods
     periods = tuple(
-        jax.tree.map(lambda *xs: jnp.stack(xs),
-                     *[layer_params[r * p_len + s] for r in range(reps)])
+        jax.vmap(functools.partial(blk.init_block, cfg=cfg, kind=kinds[s][0],
+                                   attn_kind=kinds[s][1]))(
+            keys[s:reps * p_len:p_len])
         for s in range(p_len)
-    )
-    tail = tuple(layer_params[reps * p_len:])
+    ) if reps else tuple()
+    tail = tuple(blk.init_block(keys[i], cfg, kinds[i][0], kinds[i][1])
+                 for i in range(reps * p_len, cfg.num_layers))
     params = {
         "embed": lyr.init_embedding(keys[-2], cfg),
         "periods": periods,
@@ -49,6 +51,14 @@ def init_model(key, cfg: ModelConfig) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["head"] = lyr.init_logits_head(keys[-1], cfg)
     return params
+
+
+def init_params(key, cfg: ModelConfig, sharding=None) -> Dict[str, Any]:
+    """``init_model`` as one jitted program with every leaf placed by
+    ``sharding`` (default: JAX's default device) — the serving path's
+    initialiser: nothing is materialised twice on a device."""
+    return jax.jit(functools.partial(init_model, cfg=cfg),
+                   out_shardings=sharding)(key)
 
 
 def count_params(cfg: ModelConfig) -> int:

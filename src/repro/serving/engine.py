@@ -132,6 +132,17 @@ class ReplicaSaturated(ReplicaFailure):
         self.retry_after_ms = retry_after_ms
 
 
+def _params_device(params):
+    """The one device every param leaf lives on, or None (host arrays,
+    or params spread over several devices)."""
+    devs = set()
+    for leaf in jax.tree.leaves(params):
+        if not isinstance(leaf, jax.Array):
+            return None
+        devs |= leaf.devices()
+    return next(iter(devs)) if len(devs) == 1 else None
+
+
 class ReplicaLeak(RuntimeError):
     """stop() could not join the decode thread: it is hung, not stopped."""
 
@@ -417,6 +428,12 @@ class Replica:
             self._prefix = None
         self.serving_mesh = serving_mesh
         self._mesh_axes = (mesh_batch_axis, mesh_seq_axis)
+        # one-chip replicas run where their params live: caches, host
+        # transfers and the decode thread all follow the params' device
+        self.device = _params_device(params) if serving_mesh is None else None
+        # set when the decode loop died on an exception: the replica then
+        # refuses work and every in-flight caller got that error
+        self.failure: Optional[BaseException] = None
         # UP loop: set by ServingFleet.add_replica / profile_replica; the
         # decode loop EWMAs live (occupancy, step_ms) samples into it
         self.profile: Optional[AppProfile] = None
@@ -473,12 +490,8 @@ class Replica:
         # (paged mode adds the host block-table mirror: row j is lane j's
         # position-ordered page list, -1 = absent)
         if self.paged:
-            self._cache = model_lib.init_paged_cache(
-                cfg, slots, capacity, self.num_pages, self.page_size)
             self._tables = np.full((slots, self._max_pages_per_lane), -1,
                                    np.int32)
-        else:
-            self._cache = model_lib.init_cache(cfg, slots, capacity)
         self._tok = np.zeros((slots, 1), np.int32)
         self._idx = np.zeros((slots,), np.int32)
         self._keys = np.zeros((slots, 2), np.uint32)
@@ -487,7 +500,8 @@ class Replica:
         self._topp = np.ones((slots,), np.float32)
 
         t0 = time.perf_counter()
-        with self._mesh_scope():
+        with self._scope():
+            self._cache = self._fresh_cache()
             dummy = jnp.zeros((1, 8), jnp.int32)
             logits, lane_cache = self._prefill(params, dummy)
             if self.prefill_caps["supported"]:
@@ -541,27 +555,34 @@ class Replica:
                 jnp.zeros((1, cfg.vocab_size), jnp.float32),
                 jnp.ones((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
                 jnp.ones((1,), jnp.float32))[1].block_until_ready()
-            if self.paged:
-                self._cache = model_lib.init_paged_cache(
-                    cfg, slots, capacity, self.num_pages, self.page_size)
-            else:
-                self._cache = model_lib.init_cache(cfg, slots, capacity)
+            self._cache = self._fresh_cache()
         self.warmup_s = time.perf_counter() - t0
 
         self._thread = threading.Thread(
             target=self._loop, name=f"decode-{name}", daemon=True)
         self._thread.start()
 
-    def _mesh_scope(self):
-        """Serving-mesh context for whatever thread is about to trace or
-        run decode executables (the context is thread-local, and the
-        decode loop runs on its own thread)."""
+    def _scope(self):
+        """Placement context for whatever thread is about to trace or run
+        this replica's executables: its serving mesh, or the device its
+        params live on (both contexts are thread-local, and the decode
+        loop runs on its own thread)."""
         if self.serving_mesh is None:
-            return contextlib.nullcontext()
+            if self.device is None:
+                return contextlib.nullcontext()
+            return jax.default_device(self.device)
         from repro.sharding import context as shctx
         return shctx.serving_mesh(self.serving_mesh,
                                   batch_axis=self._mesh_axes[0],
                                   seq_axis=self._mesh_axes[1])
+
+    def _fresh_cache(self):
+        """An empty batched decode cache (call under ``_scope``)."""
+        if self.paged:
+            return model_lib.init_paged_cache(
+                self.cfg, self.slots, self.capacity, self.num_pages,
+                self.page_size)
+        return model_lib.init_cache(self.cfg, self.slots, self.capacity)
 
     # ---------------------------------------------------- jitted executables
     def _step_impl(self, params, cache, tok, idx):
@@ -784,6 +805,10 @@ class Replica:
         born = req.created_ms or now
         evicted: Optional[_Job] = None
         with self._work:
+            if self.failure is not None:
+                raise ReplicaDead(
+                    self.name, f"replica {self.name}: decode loop failed: "
+                    f"{self.failure!r}")
             if self._shutdown or not self._accepting:
                 raise ReplicaRefused(
                     self.name, f"replica {self.name} is "
@@ -845,7 +870,7 @@ class Replica:
         """Batch-1 reference greedy decode (the pre-batching engine):
         whole-prompt prefill + per-token jitted step with a host sync each
         token.  Kept as the parity oracle and the benchmark baseline."""
-        with self._mesh_scope():
+        with self._scope():
             prompt = jnp.asarray(req.prompt)[None, :]
             logits, cache = self._prefill(self.params, prompt)
             out = []
@@ -954,8 +979,16 @@ class Replica:
 
     # ---------------------------------------------------- decode loop (thread)
     def _loop(self) -> None:
-        with self._mesh_scope():
-            self._loop_body()
+        with self._scope():
+            try:
+                self._loop_body()
+            except Exception as e:      # noqa: BLE001 — any step failure
+                # a step that raised on the device: fail everything in
+                # flight with that error (callers get an error result, not
+                # a hang) and refuse new work — the replica is dead
+                log.exception("replica %s: decode loop failed", self.name)
+                self.failure = e
+                self.fail_inflight(f"decode loop failed: {e!r}")
 
     def _loop_body(self) -> None:
         while True:
@@ -1374,7 +1407,7 @@ def measure_step_curve(rep: Replica, steps_per_point: int = 6,
 
     Returns ``(occupancies, step_ms, prefill_chunk_ms)``.
     """
-    with rep._mesh_scope():
+    with rep._scope():
         paged = getattr(rep, "paged", False)
         tables = None
         if paged:
@@ -1447,18 +1480,9 @@ def profile_replica(rep: Replica, prompt_lens=(8, 32, 128),
     whose per-token host syncs would inflate every lane-mode prediction
     by the sequential/batched step-time gap."""
     occs, step_ms, chunk_ms = measure_step_curve(rep, steps_per_point)
-    times = []
-    for s in prompt_lens:
-        toks = jnp.asarray(np.ones((1, s), np.int32))
-        lg, _ = rep._prefill(rep.params, toks)      # warm this shape: keep
-        jax.block_until_ready(lg)                   # compile out of the
-        best = float("inf")                         # measurement (cold start
-        for _ in range(2):                          # is a Table III/IV
-            t0 = time.perf_counter()                # concern, not warm-run)
-            lg, _ = rep._prefill(rep.params, toks)
-            jax.block_until_ready(lg)
-            best = min(best, (time.perf_counter() - t0) * 1e3)
-        times.append(best + new_tokens * step_ms[0])
+    with rep._scope():
+        times = _prefill_times(rep, prompt_lens)
+    times = [t + new_tokens * step_ms[0] for t in times]
     base = times[0]
     cont = [base + new_tokens * max(m - step_ms[0], 0.0) for m in step_ms]
     prof = AppProfile(
@@ -1475,6 +1499,23 @@ def profile_replica(rep: Replica, prompt_lens=(8, 32, 128),
         prefill_chunk_tokens=float(rep._chunk_buckets[-1]
                                    if rep.prefill_caps["supported"] else 0))
     return prof
+
+
+def _prefill_times(rep: Replica, prompt_lens) -> List[float]:
+    """Warm whole-prompt prefill wall-clock (ms) per prompt length."""
+    times = []
+    for s in prompt_lens:
+        toks = jnp.asarray(np.ones((1, s), np.int32))
+        lg, _ = rep._prefill(rep.params, toks)      # warm this shape: keep
+        jax.block_until_ready(lg)                   # compile out of the
+        best = float("inf")                         # measurement (cold start
+        for _ in range(2):                          # is a Table III/IV
+            t0 = time.perf_counter()                # concern, not warm-run)
+            lg, _ = rep._prefill(rep.params, toks)
+            jax.block_until_ready(lg)
+            best = min(best, (time.perf_counter() - t0) * 1e3)
+        times.append(best)
+    return times
 
 
 class ServingFleet:
@@ -1621,15 +1662,17 @@ class ServingFleet:
 
     # ------------------------------------------------------ failure handling
     def _stalled_replicas(self) -> List[str]:
-        """Replicas whose decode loop holds work but has not advanced for
-        ``progress_timeout_ms`` — the hang detector (a hung executable's
-        heartbeat thread keeps publishing, so staleness alone misses it)."""
+        """Replicas whose decode loop died on an exception, or holds work
+        but has not advanced for ``progress_timeout_ms`` — the hang
+        detector (a hung executable's heartbeat thread keeps publishing,
+        so staleness alone misses it)."""
         if self.progress_timeout_ms <= 0:
             return []
         with self._lock:
             reps = dict(self.replicas)
         return [n for n, r in reps.items()
-                if r.stalled_ms() > self.progress_timeout_ms]
+                if r.failure is not None
+                or r.stalled_ms() > self.progress_timeout_ms]
 
     def _on_replica_dead(self, name: str, reason: str) -> None:
         """Monitor callback: evict ``name`` from routing and fail its

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
 
 NEG_INF = -1e30
 
@@ -165,7 +164,7 @@ def spmd_decode_attention(mesh, q, k_cache, v_cache, new_k, new_v, pos,
 
     pos_spec = P(bspec, seq_axis) if pos_batched else P(seq_axis)
     idx_spec = P(bspec) if idx_batched else P()
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None, None),        # q (replicated on seq)
                   P(bspec, seq_axis, None, None),    # k cache
@@ -255,7 +254,7 @@ def spmd_paged_decode_attention(mesh, q, k_pool, v_pool, pos_pool, tables,
         out = out.reshape(b, 1, hq, d).astype(q_l.dtype)
         return out, k_l, v_l, pos_l
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, None, None),          # q (replicated)
                   P(seq_axis, None, None, None),      # k pool (rows sharded)

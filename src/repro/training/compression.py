@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
 from repro.training.optimizer import dequantize_int8, quantize_int8
 
 
@@ -44,7 +43,7 @@ def make_compressed_allreduce(mesh, axis_name: str = "data"):
             summed = int8_psum(xs, axis_name)
             return summed / mesh.shape[axis_name]
 
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=P(axis_name),
             out_specs=P(),

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
 from repro.common.config import ModelConfig
 from repro.models import blocks as blk
 
@@ -93,7 +92,7 @@ def gpipe_apply(mesh, stage_axis: str, periods_params, x_mb,
         outputs = jnp.where(s_idx == n_stages - 1, outputs, 0.0)
         return jax.lax.psum(outputs, stage_axis)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(stage_axis), periods_params),
                   P()),

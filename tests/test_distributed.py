@@ -25,10 +25,11 @@ def run_py(code: str, devices: int = 8) -> str:
 
 def test_spmd_decode_matches_reference():
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import jax, jax.numpy as jnp, numpy as np
     from repro.kernels import ref
     from repro.serving.spmd_decode import spmd_decode_attention
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     b, s, hq, hkv, d = 4, 32, 8, 2, 16
     for trial, (idx, window) in enumerate([(5, 0), (20, 8), (31, 0)]):
         ks = jax.random.split(jax.random.PRNGKey(trial), 5)
@@ -57,10 +58,11 @@ def test_spmd_decode_per_lane_matches_reference():
     slots, landing in different S-shards) must match the per-lane
     single-device reference — including per-lane sliding windows."""
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import jax, jax.numpy as jnp, numpy as np
     from repro.kernels import ref
     from repro.serving.spmd_decode import spmd_decode_attention
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     b, s, hq, hkv, d = 4, 32, 8, 2, 16
     for trial, window in enumerate([0, 8]):
         ks = jax.random.split(jax.random.PRNGKey(trial), 5)
@@ -93,6 +95,7 @@ def test_decode_step_per_lane_on_mesh_matches_single_device():
     mesh (spmd split-S decode) must equal the single-device path — the
     NotImplementedError this combination used to raise is gone."""
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke_config
     from repro.models import model as M
@@ -106,7 +109,7 @@ def test_decode_step_per_lane_on_mesh_matches_single_device():
     cache = M.init_cache(cfg, B, cap)
     lg_ref, cache_ref = jax.jit(
         lambda p,c,t,i: M.decode_step(p,c,t,i,cfg))(params, cache, tok, idx)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     with shctx.serving_mesh(mesh):
         lg_mesh, cache_mesh = jax.jit(
             lambda p,c,t,i: M.decode_step(p,c,t,i,cfg))(params, cache, tok, idx)
@@ -126,6 +129,7 @@ def test_mesh_replica_tokens_match_single_device_reference():
     identical to the plain single-device decode loop, and fixed-seed
     sampled requests must reproduce across runs on the mesh."""
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import threading, time
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke_config
@@ -147,7 +151,7 @@ def test_mesh_replica_tokens_match_single_device_reference():
             pos += 1
         return out
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = auto_mesh((1, 4), ("data", "model"))
     rep = Replica("mesh0", cfg, params, slots=2, capacity=64,
                   prefill_chunk_tokens=4, serving_mesh=mesh)
     rng = np.random.default_rng(11)
@@ -194,11 +198,70 @@ def test_mesh_replica_tokens_match_single_device_reference():
     """, devices=4)
 
 
-def test_int8_compressed_allreduce():
+def test_replicas_run_on_the_device_their_params_live_on():
+    """One process drives several devices: a Replica whose params live on
+    device 1 keeps its caches there and decodes there, token-identical to
+    a replica on device 0 (no constructor knob: placement follows the
+    params)."""
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.configs import get_smoke_config
+    from repro.launch.serve import replica_params
+    from repro.serving.engine import Replica, Request
+    cfg = get_smoke_config("qwen3-4b")
+    params = replica_params(cfg, 2)
+    devs = jax.devices()
+    reps = [Replica(f"r{i}", cfg, params[i], slots=2, capacity=64)
+            for i in range(2)]
+    assert [r.device for r in reps] == devs[:2], [r.device for r in reps]
+    for r, d in zip(reps, devs):
+        assert {next(iter(x.devices())) for x in jax.tree.leaves(r._cache)} == {d}
+    prompt = np.arange(2, 14, dtype=np.int32)
+    outs = [r.generate(Request(0, prompt, 6, 1e9)).tolist() for r in reps]
+    assert outs[0] == outs[1], outs
+    for r, d in zip(reps, devs):
+        assert {next(iter(x.devices())) for x in jax.tree.leaves(r._cache)} == {d}
+        r.stop()
+    print("OK")
+    """, devices=2)
+
+
+def test_pallas_prefill_under_serving_mesh_matches_single_device():
+    """XLA cannot partition a Pallas call: under a serving mesh the flash
+    kernel runs per device through shard_map — heads split when they
+    divide the mesh axis, replicated when not — and whole-prompt prefill
+    must equal the single-device result either way."""
+    run_py("""
+    import os
+    os.environ["REPRO_KERNEL_IMPL"] = "pallas_interpret"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import auto_mesh
+    from repro.models import model as M
+    from repro.sharding import context as shctx
+    mesh = auto_mesh((1, 4), ("data", "model"))
+    base = get_smoke_config("qwen3-4b").replace(param_dtype=jnp.float32,
+                                                dtype=jnp.float32)
+    for heads, kv in ((8, 4), (4, 2)):       # head-split, replicated
+        cfg = base.replace(num_heads=heads, num_kv_heads=kv)
+        params = M.init_model(jax.random.PRNGKey(0), cfg)
+        toks = jnp.arange(2, 18, dtype=jnp.int32)[None]
+        want, _ = jax.jit(lambda p, t: M.prefill(p, t, cfg, 32))(params, toks)
+        with shctx.serving_mesh(mesh):
+            got, _ = jax.jit(lambda p, t: M.prefill(p, t, cfg, 32))(params,
+                                                                    toks)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+    print("OK")
+    """, devices=4)
+
+
+def test_int8_compressed_allreduce():
+    run_py("""
+    from repro.launch.mesh import auto_mesh
+    import jax, jax.numpy as jnp, numpy as np
     from repro.training.compression import make_compressed_allreduce
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 128)) * 0.02
     fn = make_compressed_allreduce(mesh, "data")
     out = np.asarray(fn({"g": x})["g"])[0]
@@ -214,6 +277,7 @@ def test_sharded_train_step_matches_single_device():
     """The same train step on a 4x2 mesh and on 1 device must produce the
     same loss and (numerically) the same updated params."""
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -237,7 +301,7 @@ def test_sharded_train_step_matches_single_device():
     s1, m1 = jax.jit(step)(state, batch)
 
     # sharded
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     pc = parallel_config_for(mesh)
     specs = sp.state_specs(jax.eval_shape(lambda: state), mesh, pc)
     st_sh = sp.named(mesh, specs)
@@ -257,6 +321,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
     """Save on an 8-device (4,2) mesh, restore onto (2,2) using 4 devices —
     the elastic rescale path (checkpoint is mesh-agnostic)."""
     run_py(f"""
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     import jax, jax.numpy as jnp
     from repro.checkpoint.manager import CheckpointManager
@@ -269,7 +334,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
 
     cfg = get_smoke_config("qwen3-4b")
     state = steps_lib.init_train_state(jax.random.PRNGKey(0), cfg)
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh8 = auto_mesh((4, 2), ("data", "model"))
     specs8 = sp.state_specs(jax.eval_shape(lambda: state), mesh8,
                             parallel_config_for(mesh8))
     state8 = jax.device_put(state, sp.named(mesh8, specs8))
@@ -278,7 +343,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
 
     plan = plan_rescale(ParallelConfig(dp=4, tp=2), available_devices=4)
     assert plan.new_tp == 2 and plan.new_dp == 2
-    mesh4 = jax.make_mesh((plan.new_dp, plan.new_tp), ("data", "model"))
+    mesh4 = auto_mesh((plan.new_dp, plan.new_tp), ("data", "model"))
     pc4 = parallel_config_for(mesh4)
     template = jax.eval_shape(lambda: state)
     restored = mgr.restore(1, template)
@@ -294,6 +359,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
 def test_gpipe_pipeline_matches_forward():
     """SPMD GPipe over a 4-stage mesh must equal the plain forward."""
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import jax, jax.numpy as jnp
     from repro.configs import get_smoke_config
     from repro.models import model as M
@@ -303,7 +369,7 @@ def test_gpipe_pipeline_matches_forward():
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = auto_mesh((4,), ("stage",))
     got = jax.jit(lambda p, t: pipeline_forward(
         mesh, "stage", p, t, cfg, num_microbatches=4))(params, tokens)
     want, _ = M.forward(params, tokens, cfg)
@@ -315,6 +381,7 @@ def test_gpipe_pipeline_matches_forward():
 def test_gpipe_heterogeneous_periods():
     """Pipeline a gemma3-style (5 local + 1 global) period stack."""
     run_py("""
+    from repro.launch.mesh import auto_mesh
     import jax, jax.numpy as jnp
     from repro.configs import get_smoke_config
     from repro.models import model as M
@@ -324,7 +391,7 @@ def test_gpipe_heterogeneous_periods():
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((2,), ("stage",))
+    mesh = auto_mesh((2,), ("stage",))
     got = jax.jit(lambda p, t: pipeline_forward(
         mesh, "stage", p, t, cfg, num_microbatches=2))(params, tokens)
     want, _ = M.forward(params, tokens, cfg)

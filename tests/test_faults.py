@@ -22,7 +22,8 @@ from repro.core.telemetry import MaintainProfileTable
 from repro.ft import faults
 from repro.ft.monitor import FleetMonitor
 from repro.models import model as M
-from repro.serving.engine import (Replica, ReplicaLeak, Request, ServingFleet)
+from repro.serving.engine import (Replica, ReplicaDead, ReplicaLeak, Request,
+                                  ServingFleet)
 
 
 class PinPolicy(Policy):
@@ -253,6 +254,39 @@ def test_kill_mid_decode_fails_over_token_identical(model_setup):
     assert n_failed_over + sum(1 for r in results if not r.ok) > 0
     assert fleet.lost == sum(1 for r in results if not r.ok)
     inj.stop()
+    fleet.stop()
+
+
+def test_step_exception_fails_inflight_instead_of_hanging(model_setup):
+    """A decode step that raises (a device error) must not strand its
+    callers: the loop fails every in-flight job with that error, marks the
+    replica dead, and the fleet returns an error result within a bound."""
+    cfg, params = model_setup
+    rep = Replica("boom0", cfg, params, slots=2, capacity=64)
+    fleet = ServingFleet(make_policy("DDS"), source="boom0",
+                         coordinator="boom0", heartbeat_ms=20.0,
+                         max_attempts=2, retry_backoff_ms=5.0)
+    fleet.add_replica(rep)          # profiles with the real step first
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("device step failed")
+
+    rep._step = boom
+    prompt = np.arange(2, 10, dtype=np.int32)
+    out = {}
+    t = threading.Thread(target=lambda: out.setdefault(
+        "r", fleet.submit(Request(0, prompt, 8, 1e9))))
+    t0 = time.monotonic()
+    t.start()
+    t.join(timeout=60.0)
+    assert not t.is_alive(), "submit hung on a dead decode loop"
+    assert time.monotonic() - t0 < 60.0
+    r = out["r"]
+    assert r.outcome == "lost" and not r.ok
+    assert "device step failed" in r.error
+    assert isinstance(rep.failure, RuntimeError)
+    with pytest.raises(ReplicaDead):
+        rep.generate(Request(1, prompt, 4, 1e9))
     fleet.stop()
 
 

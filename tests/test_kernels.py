@@ -12,7 +12,8 @@ except ImportError:                      # pragma: no cover
     from _hypothesis_fallback import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (decode_attention,
+                                            paged_decode_attention)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru
 from repro.kernels.ssd_scan import ssd
@@ -67,6 +68,15 @@ def test_chunked_attention_matches_reference():
 
 
 # ------------------------------------------------------------ decode attention
+def contiguous_pos(b, smax, lens):
+    """The position plane of a cache filled from slot 0: slot ``s`` holds
+    position ``s`` below the lane's length, nothing (-1) past it."""
+    lens = jnp.broadcast_to(jnp.asarray(lens, jnp.int32).reshape(-1, 1),
+                            (b, 1))
+    slot = jnp.arange(smax, dtype=jnp.int32)[None]
+    return jnp.where(slot < lens, slot, -1)
+
+
 DECODE_CASES = [
     (2, 512, 8, 2, 64, 300, 0, jnp.float32),
     (1, 300, 4, 4, 32, 123, 64, jnp.float32),
@@ -80,7 +90,8 @@ def test_decode_attention_vs_oracle(b, smax, hq, hkv, d, clen, window, dtype):
     q = rand(k1, (b, 1, hq, d), dtype)
     kc = rand(k2, (b, smax, hkv, d), dtype)
     vc = rand(k3, (b, smax, hkv, d), dtype)
-    out = decode_attention(q, kc, vc, cache_len=clen, window=window,
+    out = decode_attention(q, kc, vc, cache_len=clen,
+                           pos=contiguous_pos(b, smax, clen), window=window,
                            interpret=True)
     exp = ref.decode_mha_reference(q, kc, vc, cache_len=clen, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -106,8 +117,9 @@ def test_decode_attention_per_lane_cache_len(b, smax, hq, hkv, d, clens,
     kc = rand(k2, (b, smax, hkv, d), dtype)
     vc = rand(k3, (b, smax, hkv, d), dtype)
     clen_vec = jnp.asarray(clens, jnp.int32)
-    out = decode_attention(q, kc, vc, cache_len=clen_vec, window=window,
-                           interpret=True)
+    out = decode_attention(q, kc, vc, cache_len=clen_vec,
+                           pos=contiguous_pos(b, smax, clen_vec),
+                           window=window, interpret=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     for lane, clen in enumerate(clens):
         exp = ref.decode_mha_reference(q[lane:lane + 1], kc[lane:lane + 1],
@@ -123,6 +135,82 @@ def test_decode_attention_per_lane_cache_len(b, smax, hq, hkv, d, clens,
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp_vec, np.float32),
                                atol=tol, rtol=tol)
+
+
+RING_CASES = [
+    # b, n, hq, hkv, d, lens, window, dtype
+    (4, 64, 8, 2, 32, (1, 40, 64, 150), 0, jnp.float32),     # 150 wraps
+    (3, 48, 4, 1, 16, (7, 100, 48), 16, jnp.float32),        # window + wrap
+    (2, 64, 8, 2, 32, (33, 90), 0, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("b,n,hq,hkv,d,lens,window,dtype", RING_CASES)
+def test_decode_attention_ring_pos_plane(b, n, hq, hkv, d, lens, window,
+                                         dtype):
+    """A ring cache passes its own slot-position plane: after the ring
+    wraps, slot s holds the newest position congruent to s, and the kernel
+    must mask by that plane (not by slot index) exactly like the jnp ring
+    path (``ref.decode_mha_masked``)."""
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    q = rand(k1, (b, 1, hq, d), dtype)
+    kc = rand(k2, (b, n, hkv, d), dtype)
+    vc = rand(k3, (b, n, hkv, d), dtype)
+    lens_a = np.asarray(lens, np.int32)
+    slot = np.arange(n)[None]
+    pos = slot + ((lens_a[:, None] - 1 - slot) // n) * n
+    pos = jnp.asarray(np.where(pos >= 0, pos, -1), jnp.int32)
+    lens_j = jnp.asarray(lens_a)
+    out = decode_attention(q, kc, vc, cache_len=lens_j, pos=pos,
+                           window=window, interpret=True)
+    valid = (pos >= 0) & (pos < lens_j[:, None])
+    if window:
+        valid &= pos > lens_j[:, None] - 1 - window
+    exp = ref.decode_mha_masked(q, kc, vc, valid_mask=valid)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32), atol=tol, rtol=tol)
+
+
+PAGED_CASES = [
+    # b, page, maxp, hq, hkv, d, lens, window, dtype
+    (3, 8, 6, 8, 2, 32, (1, 20, 48), 0, jnp.float32),
+    (2, 16, 4, 4, 1, 16, (17, 64), 24, jnp.float32),
+    (2, 8, 8, 8, 2, 32, (30, 61), 0, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("b,page,maxp,hq,hkv,d,lens,window,dtype",
+                         PAGED_CASES)
+def test_paged_decode_attention_vs_oracle(b, page, maxp, hq, hkv, d, lens,
+                                          window, dtype):
+    """Block-table paged decode: shuffled pool rows, absent table slots and
+    a recycled page whose written positions do not match must all agree
+    with ``ref.paged_decode_mha_reference``."""
+    rng = np.random.default_rng(0)
+    rows = b * maxp + 1                          # + the dump row
+    perm = rng.permutation(rows - 1).astype(np.int32).reshape(b, maxp)
+    lens_a = np.asarray(lens, np.int32)
+    tables = np.where(np.arange(maxp)[None] * page < lens_a[:, None], perm, -1)
+    pos = np.full((rows, page), -1, np.int32)
+    for lane in range(b):
+        for j in range(maxp):
+            if tables[lane, j] >= 0:
+                pos[tables[lane, j]] = j * page + np.arange(page)
+    # a previous tenant's page under the longest lane's first table slot
+    pos[perm[int(np.argmax(lens_a)), 0]] += 3
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    q = rand(k1, (b, 1, hq, d), dtype)
+    kp = rand(k2, (rows, page, hkv, d), dtype)
+    vp = rand(k3, (rows, page, hkv, d), dtype)
+    args = (q, kp, vp, jnp.asarray(pos), jnp.asarray(tables))
+    out = paged_decode_attention(*args, cache_len=jnp.asarray(lens_a),
+                                 window=window, interpret=True)
+    exp = ref.paged_decode_mha_reference(*args, cache_len=jnp.asarray(lens_a),
+                                         window=window)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32), atol=tol, rtol=tol)
 
 
 # ------------------------------------------------------------------------ SSD
