@@ -40,6 +40,8 @@ class NodeState:
     cpu_load: float = 0.0          # background load [0, 1]
     updated_ms: float = 0.0        # telemetry timestamp
     brownout: bool = False         # node is degrading service under overload
+    pages_reserved: int = 0        # KV pages admitted jobs hold (paged serving)
+    pages_used: int = 0            # ... of which live lanes have written
 
 
 def predict_process_ms(profile: DeviceProfile, task: Task,

@@ -21,6 +21,34 @@ from repro.core.profile import DeviceProfile
 
 
 @dataclass
+class LoopCounters:
+    """What a serving replica's loop did, counted where the work happens
+    (``Replica.counters``; one writer, the replica's own loop thread).  The
+    times reuse the UP loop's clock readings where it already takes them:
+    ``decode_ms`` is the sum of the ``step_ms`` samples fed to
+    ``AppProfile.observe_step``.
+
+    * ``turns`` — loop iterations; ``loop_ms`` — their time, less the time
+      spent parked with no work;
+    * ``prefill_ms`` — time in prefill (chunks, ring restores, copies,
+      commits, first-token selection); ``prefill_chunks`` — chunk launches;
+    * ``decode_steps`` / ``decode_ms`` — batched decode steps and their
+      dispatch-to-sync time;
+    * ``decode_tokens`` — tokens those steps emitted (active lanes summed
+      over steps); ``context_tokens`` — KV positions they attended (each
+      lane's live context, summed over lanes and steps)."""
+
+    turns: int = 0
+    decode_steps: int = 0
+    decode_tokens: int = 0
+    context_tokens: int = 0
+    prefill_chunks: int = 0
+    loop_ms: float = 0.0
+    prefill_ms: float = 0.0
+    decode_ms: float = 0.0
+
+
+@dataclass
 class HeartbeatRecord:
     state: NodeState
     profile: DeviceProfile

@@ -39,6 +39,11 @@ feasibility floor the fleet refuses infeasible deadlines against (0
 disables admission), and ``--brownout`` arms queue-pressure brownout on
 each replica.  The summary then accounts every request by outcome:
 ok / rejected / shed / lost.
+
+At exit each replica reports its serving loop (docs/SERVING.md, "Spans and
+counters"): queue wait p50/p90 (lane and KV pages), the share of loop
+time spent in prefill, the router's ``T_que + T_process`` error against
+the observed latency, and the loop's counts.
 """
 from __future__ import annotations
 
@@ -117,6 +122,31 @@ def build_fleet(cfg, policy_name: str, replicas: int = 2,
               f"{'on' if rep.prefill_caps['supported'] else 'off'} "
               f"(budget ceiling {rep.prefill_chunk_tokens} tokens); {paging}")
     return fleet
+
+
+def _pct(values: List[float], q: float, unit: str) -> str:
+    return f"{np.percentile(values, q):.1f}{unit}" if values else "n/a"
+
+
+def loop_report(results, replicas) -> List[str]:
+    """One line per replica: what its serving loop did for ``results``
+    (the ``RequestResult``s it served) and over its life (``counters``)."""
+    lines = []
+    for rep in replicas:
+        mine = [r for r in results if r.ok and r.replica == rep.name]
+        waits = [r.admitted_ms - r.enqueued_ms for r in mine]
+        errs = [100.0 * abs(r.latency_ms() - r.predicted_ms) / r.latency_ms()
+                for r in mine if r.predicted_ms > 0.0 and r.latency_ms() > 0.0]
+        c = rep.counters
+        share = 100.0 * c.prefill_ms / c.loop_ms if c.loop_ms > 0.0 else 0.0
+        lines.append(
+            f"{rep.name}: queue wait p50={_pct(waits, 50, 'ms')} "
+            f"p90={_pct(waits, 90, 'ms')} prefill share={share:.1f}% "
+            f"predictor error p50={_pct(errs, 50, '%')} "
+            f"p90={_pct(errs, 90, '%')} "
+            f"({c.turns} turns, {c.decode_steps} decode steps, "
+            f"{c.decode_tokens} tokens, {c.prefill_chunks} prefill chunks)")
+    return lines
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -253,6 +283,8 @@ def main():
         print(f"chaos summary: failed={failed} failed_over={failovers} "
               f"fleet_failovers={fleet.failovers} lost={fleet.lost} "
               f"dead={fleet.dead}")
+    for line in loop_report(results, list(fleet.replicas.values())):
+        print(line)
     fleet.stop()
     if outcomes["lost"]:
         # shed / rejected are overload control's accounted answers; a

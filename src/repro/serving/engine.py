@@ -72,6 +72,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common import config as cfg_lib
 from repro.common.config import ModelConfig
@@ -80,7 +81,8 @@ from repro.core.latency import (NodeState, Task, predict_process_ms,
                                 predict_queue_ms, predict_total_ms)
 from repro.core.policies import LOCAL, NodeView, Policy
 from repro.core.profile import AppProfile, Curve, DeviceProfile, LinkProfile
-from repro.core.telemetry import MaintainProfileTable, UpdateProfilePublisher
+from repro.core.telemetry import (LoopCounters, MaintainProfileTable,
+                                  UpdateProfilePublisher)
 from repro.ft.monitor import FleetMonitor
 from repro.models import model as model_lib
 from repro.serving import sampling as sampling_lib
@@ -143,6 +145,32 @@ def _params_device(params):
     return next(iter(devs)) if len(devs) == 1 else None
 
 
+def insert(cache, lane_cache, lane):
+    """Splice a finished B=1 prefill cache into lane ``lane`` of the
+    batched cache.  Period-stacked leaves carry batch at axis 1 (the
+    leading axis is the scan-stack), tail leaves at axis 0."""
+    def upd(axis):
+        def f(dst, src):
+            start = tuple(lane if i == axis else 0 for i in range(dst.ndim))
+            return jax.lax.dynamic_update_slice(
+                dst, src.astype(dst.dtype), start)
+        return f
+    return {
+        "periods": jax.tree.map(upd(1), cache["periods"],
+                                lane_cache["periods"]),
+        "tail": jax.tree.map(upd(0), cache["tail"], lane_cache["tail"]),
+    }
+
+
+# the phases of a replica's serving loop, each a profiler span
+# ``dds.<replica>.<phase>``: ``turn`` holds one loop iteration, ``admit``
+# (shed sweep and admission, lock wait included) holds ``park`` (waiting
+# with no work); ``prefill`` and ``decode`` hold their device syncs and the
+# host work after them
+LOOP_SPANS = ("turn", "park", "admit", "prefill", "prefill.sync",
+              "prefill.install", "decode", "decode.sync", "decode.retire")
+
+
 class ReplicaLeak(RuntimeError):
     """stop() could not join the decode thread: it is hung, not stopped."""
 
@@ -198,7 +226,15 @@ class RequestResult:
     re-routed at least once), ``failed_over`` marks completion on a replica
     other than the first placement, ``ttft_ms`` is time to first token
     (0.0 when none decoded), and ``degraded`` marks a response served
-    under brownout (clamped decode budget)."""
+    under brownout (clamped decode budget).
+
+    The stamps of a served request split its TTFT, all on the host
+    monotonic clock of ``created_ms``: ``enqueued_ms`` (the serving
+    replica queued it), ``admitted_ms`` (it got a lane and its KV pages)
+    and ``first_ms`` (its first token), so that ``ttft_ms == first_ms -
+    created_ms``.  ``predicted_ms`` is the router's ``T_que + T_process``
+    for the placement, to set against ``latency_ms()``.  Each is 0.0 when
+    unset."""
 
     request_id: int
     tokens: np.ndarray
@@ -213,6 +249,10 @@ class RequestResult:
     ttft_ms: float = 0.0
     retry_after_ms: float = 0.0
     degraded: bool = False
+    enqueued_ms: float = 0.0
+    admitted_ms: float = 0.0
+    first_ms: float = 0.0
+    predicted_ms: float = 0.0
 
     @property
     def ok(self) -> bool:
@@ -229,8 +269,9 @@ class _Job:
     """One request's life inside the batched decoder."""
 
     __slots__ = ("req", "lane", "lane_cache", "consumed", "out", "remaining",
-                 "done", "key", "stops", "error", "order", "first_ms",
-                 "degraded", "pages", "matched", "cow")
+                 "done", "key", "stops", "error", "order", "created_ms",
+                 "enqueued_ms", "admitted_ms", "first_ms", "degraded",
+                 "pages", "matched", "cow")
 
     def __init__(self, req: Request):
         self.req = req
@@ -245,7 +286,13 @@ class _Job:
         # set at enqueue; the seq tiebreak keeps same-class same-deadline
         # traffic FIFO
         self.order: Tuple[int, float, int] = (0, 0.0, 0)
-        self.first_ms = 0.0             # wall-clock of the first token (TTFT)
+        # lifecycle stamps, host monotonic ms: created (the request's own
+        # stamp, else its enqueue), queued, given a lane and pages, first
+        # token (TTFT)
+        self.created_ms = 0.0
+        self.enqueued_ms = 0.0
+        self.admitted_ms = 0.0
+        self.first_ms = 0.0
         self.degraded = False           # admitted under brownout clamping
         # paged mode: position-ordered KV pages this job holds a ref on
         # (matched prefix pages first, then fresh allocations), the number
@@ -264,6 +311,11 @@ class _Job:
     @property
     def sampled(self) -> bool:
         return self.key is not None
+
+    @property
+    def ttft_ms(self) -> float:
+        """Created to first token; 0.0 when no token came."""
+        return self.first_ms - self.created_ms if self.first_ms > 0.0 else 0.0
 
     def hit_stop(self) -> bool:
         """True if the last emitted token was ``eos_id`` or completed a
@@ -335,7 +387,9 @@ class Replica:
     (occupancy, step_ms) and chunk-cost samples into it — the paper's
     Update-Profile writer, and the signal ``budget_tokens`` adapts on.
     ``state()``/``free_slots()`` are the telemetry the UP heartbeat
-    publishes.
+    publishes.  ``counters`` (a ``LoopCounters``) counts what the loop
+    did, and every loop phase runs inside a profiler span named
+    ``dds.<replica>.<phase>`` (``LOOP_SPANS``; docs/SERVING.md).
 
     Weights + jitted prefill/decode/insert/sample executables are built
     (and compiled) at construction.  Chunked prefill is **universal**
@@ -394,8 +448,11 @@ class Replica:
         self.paged = bool(paged)
         self.page_size = int(page_size)
         self.cow_copies = 0             # COW page copies performed
-        self.prefill_chunks = 0         # chunk launches (all modes)
         self.prefilled_tokens = 0       # prompt tokens actually computed
+        self.counters = LoopCounters()
+        # profiler span names, built once: every Python thread shows as the
+        # same line name on the host plane, so the replica is in the name
+        self._span = {ph: f"dds.{name}.{ph}" for ph in LOOP_SPANS}
         if self.paged:
             if not self.prefill_caps["supported"]:
                 raise ValueError(
@@ -457,33 +514,44 @@ class Replica:
         # replica never reads as stalled the moment it gets a request
         self._last_progress_ms = time.monotonic() * 1e3
 
-        # warm the executables (cold start happens HERE, not on requests)
-        self._prefill = jax.jit(
-            lambda p, toks: model_lib.prefill(p, toks, cfg, capacity))
+        # warm the executables (cold start happens HERE, not on requests).
+        # Each program is a named function: the device trace names a
+        # program after it (``jit_prefill_chunk``, ``jit_commit``, ...)
+        def prefill(p, toks):
+            return model_lib.prefill(p, toks, cfg, capacity)
+
         # chunks are exact (never padded) and always one of the power-of-two
         # bucket widths, so the chunk executable compiles once per bucket
-        self._prefill_chunk = jax.jit(
-            lambda p, c, toks, start: model_lib.prefill_chunk(
-                p, c, toks, start, cfg))
-        self._decode = jax.jit(
-            lambda p, cache, tok, idx: model_lib.decode_step(
-                p, cache, tok, idx, cfg))
+        def prefill_chunk(p, c, toks, start):
+            return model_lib.prefill_chunk(p, c, toks, start, cfg)
+
+        def decode(p, cache, tok, idx):
+            return model_lib.decode_step(p, cache, tok, idx, cfg)
+
+        self._prefill = jax.jit(prefill)
+        self._prefill_chunk = jax.jit(prefill_chunk)
+        self._decode = jax.jit(decode)
         self._step = jax.jit(self._step_impl)
         self._step_sampled = jax.jit(self._step_sampled_impl)
         self._sample_first = jax.jit(sampling_lib.sample_lane_tokens)
-        self._insert = jax.jit(self._insert_impl)
+        self._insert = jax.jit(insert)
         if self.paged:
             ps = self.page_size
+
+            def commit(c, lc, lane, row, fp):
+                return model_lib.paged_commit(c, lc, lane, row, fp, cfg, ps)
+
+            def restore(c, lc, row, m):
+                return model_lib.paged_restore(c, lc, row, m, cfg, ps)
+
+            def copy_page(c, src, dst):
+                return model_lib.paged_copy_page(c, src, dst, cfg)
+
             self._step_paged = jax.jit(self._step_paged_impl)
             self._step_sampled_paged = jax.jit(self._step_sampled_paged_impl)
-            self._commit = jax.jit(
-                lambda c, lc, lane, row, fp: model_lib.paged_commit(
-                    c, lc, lane, row, fp, cfg, ps))
-            self._restore = jax.jit(
-                lambda c, lc, row, m: model_lib.paged_restore(
-                    c, lc, row, m, cfg, ps))
-            self._copy_page = jax.jit(
-                lambda c, s, d: model_lib.paged_copy_page(c, s, d, cfg))
+            self._commit = jax.jit(commit)
+            self._restore = jax.jit(restore)
+            self._copy_page = jax.jit(copy_page)
 
         # persistent batched decode state (device) + tiny host mirrors:
         # next token, KV index, PRNG key and sampling knobs per lane
@@ -603,23 +671,6 @@ class Replica:
         keys, nxt = sampling_lib.sample_lane_tokens(keys, logits[:, -1],
                                                     temp, topk, topp)
         return nxt, keys, cache
-
-    def _insert_impl(self, cache, lane_cache, lane):
-        """Splice a finished B=1 prefill cache into lane ``lane`` of the
-        batched cache.  Period-stacked leaves carry batch at axis 1 (the
-        leading axis is the scan-stack), tail leaves at axis 0."""
-        def upd(axis):
-            def f(dst, src):
-                start = tuple(lane if i == axis else 0
-                              for i in range(dst.ndim))
-                return jax.lax.dynamic_update_slice(
-                    dst, src.astype(dst.dtype), start)
-            return f
-        return {
-            "periods": jax.tree.map(upd(1), cache["periods"],
-                                    lane_cache["periods"]),
-            "tail": jax.tree.map(upd(0), cache["tail"], lane_cache["tail"]),
-        }
 
     def _step_paged_impl(self, params, cache, tok, idx, tables):
         """Greedy decode step over the paged pools: identical to
@@ -784,6 +835,13 @@ class Replica:
         Returns ``(tokens, ttft_ms, degraded)``; ``ttft_ms`` is measured
         from ``req.created_ms`` (or enqueue, if the caller never stamped
         it) to the first emitted token."""
+        job = self._serve(req)
+        return np.asarray(job.out, np.int32), job.ttft_ms, job.degraded
+
+    def _serve(self, req: Request) -> _Job:
+        """``generate_ex``'s work: queue ``req``, block until it is served
+        and return its finished job, lifecycle stamps included; raise
+        what the replica raised for it."""
         if len(req.prompt) == 0:
             # reject in the CALLER's thread: an empty prompt reaching the
             # decode thread would kill it and strand every other lane
@@ -801,8 +859,7 @@ class Replica:
                     f"replica {self.name}: prompt of {len(req.prompt)} "
                     f"tokens exceeds paged KV capacity {limit}")
         job = _Job(req)
-        now = time.monotonic() * 1e3
-        born = req.created_ms or now
+        job.created_ms = born = req.created_ms or time.monotonic() * 1e3
         evicted: Optional[_Job] = None
         with self._work:
             if self.failure is not None:
@@ -851,16 +908,16 @@ class Replica:
                     list(worst.out),
                     retry_after_ms=self._retry_after_hint())
                 evicted = worst
+            job.enqueued_ms = time.monotonic() * 1e3
             bisect.insort(self._pending, job, key=lambda j: j.order)
-            self._last_progress_ms = time.monotonic() * 1e3
+            self._last_progress_ms = job.enqueued_ms
             self._work.notify()
         if evicted is not None:
             evicted.done.set()
         job.done.wait()
         if job.error is not None:
             raise job.error
-        ttft = (job.first_ms - born) if job.first_ms > 0.0 else 0.0
-        return np.asarray(job.out, np.int32), ttft, job.degraded
+        return job
 
     def generate(self, req: Request) -> np.ndarray:
         """``generate_ex`` without the telemetry tuple (tokens only)."""
@@ -990,51 +1047,77 @@ class Replica:
                 self.failure = e
                 self.fail_inflight(f"decode loop failed: {e!r}")
 
+    def _idle_locked(self) -> bool:
+        return (not self._shutdown and not self._pending
+                and not self._prefilling
+                and all(j is None for j in self._lanes))
+
+    def _park_locked(self) -> None:
+        """Wait for work (caller holds the lock)."""
+        with TraceAnnotation(self._span["park"]):
+            while self._idle_locked():
+                if self.browned_out:
+                    # idle = pressure is definitionally gone: feed clear
+                    # samples so brownout restores while parked instead of
+                    # waiting for the next traffic burst
+                    self.brownout.observe(0.0, 0)
+                    self._work.wait(0.01)
+                else:
+                    self._work.wait()
+
     def _loop_body(self) -> None:
         while True:
-            with self._work:
-                while (not self._shutdown and not self._pending
-                       and not self._prefilling
-                       and all(j is None for j in self._lanes)):
-                    if self.browned_out:
-                        # idle = pressure is definitionally gone: feed
-                        # clear samples so brownout restores while parked
-                        # instead of waiting for the next traffic burst
-                        self.brownout.observe(0.0, 0)
-                        self._work.wait(0.01)
-                    else:
-                        self._work.wait()
-                if self._shutdown:
-                    stranded = (list(self._pending) + list(self._prefilling)
-                                + [j for j in self._lanes if j is not None])
-                    self._lanes = [None] * self.slots
-                    for j in stranded:
-                        self._release_pages_locked(j)
-                        j.done.set()    # callers get whatever decoded so far
+            with TraceAnnotation(self._span["turn"]):
+                if not self._turn():
                     return
-                # shed: queued jobs whose predicted wait already exceeds
-                # their remaining slack will only burn lanes — drop them
-                # now (lowest priority / latest deadline first, since the
-                # queue is ordered and position inflates predicted wait)
-                shed = self._shed_sweep_locked(time.monotonic() * 1e3)
-                # admit: waiting requests claim free lanes (paged mode also
-                # reserves their KV pages all-or-nothing)
-                shed += self._admit_locked()
-                active = [i for i, j in enumerate(self._lanes)
-                          if j is not None]
-                # snapshot the prefill head under the lock: fail_inflight
-                # may clear the deque from the monitor thread at any time
-                head = self._prefilling[0] if self._prefilling else None
-            for j in shed:
-                j.done.set()
 
-            # one prefill chunk for the oldest admitted prompt — budgeted
-            # work, so in-flight decodes stall at most the SLO slack
-            if head is not None:
+    def _turn(self) -> bool:
+        """One loop iteration: admit, advance one prefill, run one decode
+        step.  False once the replica has shut down."""
+        counters = self.counters
+        t0 = time.perf_counter()
+        with TraceAnnotation(self._span["admit"]), self._work:
+            if self._idle_locked():
+                self._park_locked()
+                t0 = time.perf_counter()    # parked time is not loop time
+            if self._shutdown:
+                stranded = (list(self._pending) + list(self._prefilling)
+                            + [j for j in self._lanes if j is not None])
+                self._lanes = [None] * self.slots
+                for j in stranded:
+                    self._release_pages_locked(j)
+                    j.done.set()    # callers get whatever decoded so far
+                return False
+            # shed: queued jobs whose predicted wait already exceeds
+            # their remaining slack will only burn lanes — drop them
+            # now (lowest priority / latest deadline first, since the
+            # queue is ordered and position inflates predicted wait)
+            shed = self._shed_sweep_locked(time.monotonic() * 1e3)
+            # admit: waiting requests claim free lanes (paged mode also
+            # reserves their KV pages all-or-nothing)
+            shed += self._admit_locked()
+            active = [i for i, j in enumerate(self._lanes)
+                      if j is not None]
+            # snapshot the prefill head under the lock: fail_inflight
+            # may clear the deque from the monitor thread at any time
+            head = self._prefilling[0] if self._prefilling else None
+        for j in shed:
+            j.done.set()
+
+        # one prefill chunk for the oldest admitted prompt — budgeted
+        # work, so in-flight decodes stall at most the SLO slack
+        if head is not None:
+            t1 = time.perf_counter()
+            with TraceAnnotation(self._span["prefill"]):
                 self._advance_prefill(head, len(active))
+            counters.prefill_ms += (time.perf_counter() - t1) * 1e3
 
-            if active:
+        if active:
+            with TraceAnnotation(self._span["decode"]):
                 self._decode_step(active)
+        counters.turns += 1
+        counters.loop_ms += (time.perf_counter() - t0) * 1e3
+        return True
 
     def _shed_sweep_locked(self, now_ms: float) -> List[_Job]:
         """Walk the pending queue in order and drop every job whose
@@ -1110,6 +1193,7 @@ class Replica:
             self._pending.pop(0)
             lane = free.pop(0)
             job.lane = lane
+            job.admitted_ms = time.monotonic() * 1e3
             # NOTE: the lane's block-table row is NOT published here.  The
             # batched decode step processes every lane slot (ghost lanes'
             # tokens are discarded host-side), so a mid-prefill lane whose
@@ -1198,18 +1282,25 @@ class Replica:
                 # sync so the UP sample is the chunk's real wall-clock, not
                 # its async-dispatch time (the decode stream pays the
                 # compute either way — this only defers host bookkeeping)
-                jax.block_until_ready(logits)
+                with TraceAnnotation(self._span["prefill.sync"]):
+                    jax.block_until_ready(logits)
                 prof.observe_prefill_chunk((time.perf_counter() - t0) * 1e3,
                                            tokens=w)
             job.consumed += w
-            self.prefill_chunks += 1
+            self.counters.prefill_chunks += 1
             self.prefilled_tokens += w
         self._last_progress_ms = time.monotonic() * 1e3
         if job.consumed < n:
             return
-        # prompt fully prefilled: splice the lane in and emit token 0 —
-        # sampled from the prefill logits with the job's own key (one
-        # split, same discipline as every decode step), argmax otherwise
+        with TraceAnnotation(self._span["prefill.install"]):
+            self._install(job, logits)
+
+    def _install(self, job: _Job, logits) -> None:
+        """A prompt is fully prefilled: splice its lane in and emit token 0
+        — sampled from the prefill logits with the job's own key (one
+        split, same discipline as every decode step), argmax otherwise."""
+        prompt = job.req.prompt
+        n = len(prompt)
         if job.sampled:
             keys, tok0 = self._sample_first(
                 jnp.asarray(job.key[None]),
@@ -1296,6 +1387,7 @@ class Replica:
 
     def _decode_step(self, active: List[int]) -> None:
         t0 = time.perf_counter()
+        keys = None
         # the all-greedy batch takes the argmax-only hot path; any sampled
         # active lane switches the whole step to the per-lane sampling
         # executable (greedy lanes still argmax inside it, and every
@@ -1313,13 +1405,6 @@ class Replica:
                     jnp.asarray(self._idx), jnp.asarray(self._keys),
                     jnp.asarray(self._temp), jnp.asarray(self._topk),
                     jnp.asarray(self._topp))
-            # copy back keys for ACTIVE lanes only: a lane that joined
-            # after `active` was snapshotted had this step's token
-            # discarded, so its key must not consume this step's split —
-            # a lane's key position is exactly its own token count
-            keys_np = np.asarray(keys)
-            for lane in active:
-                self._keys[lane] = keys_np[lane]
         elif self.paged:
             nxt, self._cache = self._step_paged(
                 self.params, self._cache, jnp.asarray(self._tok),
@@ -1328,9 +1413,28 @@ class Replica:
             nxt, self._cache = self._step(self.params, self._cache,
                                           jnp.asarray(self._tok),
                                           jnp.asarray(self._idx))
-        nxt_np = np.asarray(nxt)        # the one (slots,) transfer per step
+        with TraceAnnotation(self._span["decode.sync"]):
+            nxt_np = np.asarray(nxt)    # the one (slots,) transfer per step
+        if keys is not None:
+            # copy back keys for ACTIVE lanes only: a lane that joined
+            # after `active` was snapshotted had this step's token
+            # discarded, so its key must not consume this step's split —
+            # a lane's key position is exactly its own token count
+            keys_np = np.asarray(keys)
+            for lane in active:
+                self._keys[lane] = keys_np[lane]
         self._last_progress_ms = time.monotonic() * 1e3
         step_ms = (time.perf_counter() - t0) * 1e3
+        with TraceAnnotation(self._span["decode.retire"]):
+            self._retire(active, nxt_np, step_ms)
+
+    def _retire(self, active: List[int], nxt_np: np.ndarray,
+                step_ms: float) -> None:
+        """Hand a decode step's tokens to their lanes, free the lanes that
+        finished, and count the step."""
+        counters = self.counters
+        counters.decode_steps += 1
+        counters.decode_ms += step_ms
         prof = self.profile             # Update-Profile: live step telemetry
         if prof is not None:
             prof.observe_step(len(active), step_ms)
@@ -1344,6 +1448,8 @@ class Replica:
                 job = self._lanes[lane]
                 if job is None:
                     continue
+                counters.decode_tokens += 1
+                counters.context_tokens += int(self._idx[lane]) + 1
                 job.out.append(int(nxt_np[lane]))
                 job.remaining -= 1
                 self._tok[lane, 0] = nxt_np[lane]
@@ -1375,14 +1481,25 @@ class Replica:
         ``queued`` too made every consumer double-charge them (capacity
         math subtracted them and T_que priced them as waiting work).
         ``brownout`` rides along so the Update-Profile heartbeat advertises
-        degradation honestly to routing."""
+        degradation honestly to routing, and in paged mode
+        ``pages_reserved`` / ``pages_used`` show KV reserved but not yet
+        written."""
         with self._lock:
             running = sum(1 for j in self._lanes if j is not None)
             reserved = len(self._prefilling)
             queued = len(self._pending)
+            # KV pages admitted jobs hold, and the part of them live lanes
+            # have written (positions below the lane's index)
+            pages_reserved = sum(len(j.pages) for j in self._prefilling)
+            pages_used = 0
+            for lane, j in enumerate(self._lanes):
+                if j is not None and j.pages:
+                    pages_reserved += len(j.pages)
+                    pages_used += -(-int(self._idx[lane]) // self.page_size)
         return NodeState(running=running, queued=queued, reserved=reserved,
                          brownout=self.browned_out,
-                         updated_ms=time.monotonic() * 1e3)
+                         updated_ms=time.monotonic() * 1e3,
+                         pages_reserved=pages_reserved, pages_used=pages_used)
 
     def free_slots(self) -> int:
         """Lanes not occupied or reserved by an in-progress prefill.
@@ -1762,6 +1879,24 @@ class ServingFleet:
         return self.policy.decide_coordinator(
             task, now, self._view(cname, coordinator, exact=True), peers)
 
+    def _task(self, req: Request) -> Task:
+        return Task(task_id=req.request_id, app_id="serve",
+                    size_kb=float(len(req.prompt)), created_ms=req.created_ms,
+                    constraint_ms=req.deadline_ms, source=self.source)
+
+    def _predicted_ms(self, task: Task, name: str,
+                      rep: Replica) -> Optional[float]:
+        """The paper's predicted ``T_task`` (``T_que + T_process``, plus
+        the transfers when ``name`` is not the source) of ``task`` on
+        ``name``, from the MP table's view; None for an unprofiled
+        replica."""
+        prof = self.profiles.get(name)
+        if prof is None or "serve" not in prof.apps:
+            return None
+        view = self._view(name, rep)
+        return predict_total_ms(view.profile, task, view.state,
+                                remote=(name != self.source))
+
     def _retry_viable(self, req: Request, members: Dict[str, Replica]) -> bool:
         """Deadline-aware retry gate: re-route only when some survivor's
         predicted T_task still fits the remaining SLO slack (the paper's
@@ -1771,17 +1906,10 @@ class ServingFleet:
         slack = req.deadline_ms - (now - req.created_ms)
         if slack <= 0:
             return False
-        task = Task(task_id=req.request_id, app_id="serve",
-                    size_kb=float(len(req.prompt)), created_ms=req.created_ms,
-                    constraint_ms=req.deadline_ms, source=self.source)
-        for name in members:
-            prof = self.profiles.get(name)
-            if prof is None or "serve" not in prof.apps:
-                continue
-            view = self._view(name, members[name])
-            t = predict_total_ms(view.profile, task, view.state,
-                                 remote=(name != self.source))
-            if t <= slack:
+        task = self._task(req)
+        for name, rep in members.items():
+            t = self._predicted_ms(task, name, rep)
+            if t is not None and t <= slack:
                 return True
         return False
 
@@ -1806,10 +1934,7 @@ class ServingFleet:
         queueing.  Returns the rejected result, or None to admit."""
         if self.admission_margin <= 0.0:
             return None
-        task = Task(task_id=req.request_id, app_id="serve",
-                    size_kb=float(len(req.prompt)),
-                    created_ms=req.created_ms, constraint_ms=req.deadline_ms,
-                    source=self.source)
+        task = self._task(req)
         with self._lock:
             profiles = dict(self.profiles)
         ok, floor = admit(profiles, task, self.source, self.admission_margin)
@@ -1855,7 +1980,8 @@ class ServingFleet:
         the failure mode is visible, never a hang or a silently truncated
         stream."""
         req.created_ms = req.created_ms or time.monotonic() * 1e3
-        rejected = self._admission_check(req)
+        with TraceAnnotation("dds.admission", request_id=req.request_id):
+            rejected = self._admission_check(req)
         if rejected is not None:
             return rejected
         attempts = 0
@@ -1875,7 +2001,10 @@ class ServingFleet:
                            if n not in tripped}
             avoid = last_err.replica if last_err is not None else None
             try:
-                name = self._route(req, members, avoid=avoid)
+                with TraceAnnotation("dds.route", request_id=req.request_id):
+                    name = self._route(req, members, avoid=avoid)
+                    predicted = self._predicted_ms(self._task(req), name,
+                                                   members[name]) or 0.0
             except ReplicaRefused as e:
                 last_err = e
                 break                   # no live replicas: nothing to wait for
@@ -1892,14 +2021,17 @@ class ServingFleet:
                 if attempts > 1:
                     self.failovers += 1
             try:
-                toks, ttft, degraded = members[name].generate_ex(req)
+                job = members[name]._serve(req)
                 if brk is not None:
                     brk.on_success()
                 return RequestResult(
-                    req.request_id, toks, time.monotonic() * 1e3, name,
-                    req.created_ms, attempts=attempts,
-                    failed_over=(name != first_name),
-                    priority=req.priority, ttft_ms=ttft, degraded=degraded)
+                    req.request_id, np.asarray(job.out, np.int32),
+                    time.monotonic() * 1e3, name, req.created_ms,
+                    attempts=attempts, failed_over=(name != first_name),
+                    priority=req.priority, ttft_ms=job.ttft_ms,
+                    degraded=job.degraded, enqueued_ms=job.enqueued_ms,
+                    admitted_ms=job.admitted_ms, first_ms=job.first_ms,
+                    predicted_ms=predicted)
             except ReplicaSaturated as e:
                 # the replica answered (it is alive, just overloaded):
                 # success for the breaker, terminal shed for the request

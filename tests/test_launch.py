@@ -34,6 +34,32 @@ def test_serve_cli_no_smoke_flag():
     assert not build_parser().parse_args(["--no-smoke"]).smoke
 
 
+def test_loop_report_reads_stamps_and_counters():
+    from types import SimpleNamespace
+
+    from repro.core.telemetry import LoopCounters
+    from repro.launch.serve import loop_report
+    from repro.serving.engine import RequestResult
+
+    def served(rid, wait, predicted, replica="replica0"):
+        return RequestResult(rid, np.zeros(4, np.int32), 200.0, replica, 100.0,
+                             enqueued_ms=101.0, admitted_ms=101.0 + wait,
+                             first_ms=150.0, predicted_ms=predicted)
+
+    results = [served(0, 2.0, 50.0), served(1, 4.0, 150.0),
+               served(2, 9.0, 0.0, replica="replica1")]
+    reps = [SimpleNamespace(name="replica0", counters=LoopCounters(
+        turns=8, decode_steps=6, decode_tokens=9, prefill_chunks=2,
+        loop_ms=400.0, prefill_ms=100.0)),
+        SimpleNamespace(name="replica2", counters=LoopCounters())]
+    first, idle = loop_report(results, reps)
+    # waits 2 and 4 ms; |100 - 50| / 100 and |100 - 150| / 100
+    assert first == ("replica0: queue wait p50=3.0ms p90=3.8ms prefill "
+                     "share=25.0% predictor error p50=50.0% p90=50.0% "
+                     "(8 turns, 6 decode steps, 9 tokens, 2 prefill chunks)")
+    assert "queue wait p50=n/a" in idle and "share=0.0%" in idle
+
+
 def test_init_model_stacks_layers_as_per_layer_draws():
     """Each period slot is one vmap over its layers' keys: layer r must hold
     exactly what ``init_block`` draws from that layer's own key."""
