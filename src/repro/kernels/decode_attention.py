@@ -1,7 +1,7 @@
 """Pallas TPU decode attention (flash-decode over a KV cache).
 
 Decode is memory-bound: one query token must stream the whole KV cache
-HBM->VMEM once.  Design:
+HBM->VMEM once.  The ring kernel (``decode_attention``):
   * grid = (batch, kv_blocks); kv_blocks is ``arbitrary`` (sequential) so
     the (m, l, acc) scratch accumulates while Mosaic pipelines the next KV
     tile's DMA behind the current tile's FLOPs — the streaming overlap IS
@@ -17,6 +17,26 @@ HBM->VMEM once.  Design:
     inside the sliding window, so slot order is irrelevant.
     ``cache_len`` is a per-sequence ``(B,)`` vector in SMEM, so a
     continuous-batching decode step mixes lanes at arbitrary positions.
+
+The paged kernel (``paged_decode_attention``) reads a pool of pages shared
+by all lanes through per-lane block tables, and streams only live pages:
+  * The pools stay in HBM; the kernel issues its own DMAs.  The grid is one
+    step per lane, and a ``fori_loop`` walks that lane's live blocks only:
+    from the block where its sliding window starts to ``ceil(len / blk)``,
+    with the block table and lengths in scalar prefetch.
+  * A block is ``BLOCK_TOKENS // page`` table slots (capped by the table):
+    one DMA per mapped page below the lane's length for each of K, V and
+    the position row, into one half of a double-buffered VMEM block.  The
+    next block's DMAs (after a lane's last block, the next lane's first)
+    go out before the current block is computed.
+  * So a slot past the length, with no page, or before the window costs
+    neither a DMA nor a grid step, and a lane of length 0 costs neither.
+  * A block's scores for all q heads are one matmul over its
+    ``(blk * Hkv, D)`` rows, each head masked to its own KV head's rows,
+    and ``p . v`` one more: Hkv times the FLOPs of per-head matmuls, but
+    contiguous loads in place of one strided load per head.
+    Masking re-checks each gathered position against the slot's absolute
+    position, so a recycled or half-written page never leaks stale keys.
 
 Validated on CPU with ``interpret=True`` against ``ref.decode_mha_reference``
 and ``ref.paged_decode_mha_reference``, and compiled for v5e in
@@ -174,41 +194,188 @@ def decode_attention(q, k_cache, v_cache, *, cache_len, pos, window=0,
     return out.reshape(b, 1, hq, d)
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, pos_ref,
-                         o_ref, m_scr, l_scr, acc_scr,
-                         *, scale, window, softcap, page, max_pages, hkv):
-    b_ = pl.program_id(0)
-    pi = pl.program_id(1)
-    cache_len = len_ref[b_]
-    page_id = tbl_ref[b_ * max_pages + pi]
+# Tokens of KV one DMA block of the paged kernel holds: the pages per block
+# are this over the page size (at least one page, at most the table).
+BLOCK_TOKENS = 256
 
-    @pl.when(pi == 0)
-    def _init():
-        _init_scratch(m_scr, l_scr, acc_scr)
 
-    # A table slot with no page mapped, beyond this lane's length, or (with
-    # a sliding window) wholly before it contributes nothing — and because
-    # the BlockSpec index map routed an absent slot to a clamped row, the
-    # gathered tile may be another lane's page: it must never reach the MXU.
-    lane_live = (page_id >= 0) & (pi * page < cache_len)
-    if window > 0:
-        lane_live &= (pi + 1) * page > cache_len - 1 - window
+def _spread_slots(ok, page, hkv):
+    """``(pages, page)`` slot flags -> the ``(1, pages * page * hkv)`` row
+    of a block's scores, whose lane ``t * hkv + h`` is token ``t`` of KV
+    head ``h``.  Mosaic has no reshape that moves sublanes into lanes, so
+    this is a 0/1 matmul (exact at any precision): ``ok @ T`` repeats page
+    j's flags, each ``hkv`` times, across the whole row, and the selector
+    keeps each repeat only in page j's own lanes."""
+    n = ok.shape[0]
+    width = n * page * hkv
+    lane = jax.lax.broadcasted_iota(jnp.int32, (page, width), 1)
+    tile = (lane // hkv) % page == jax.lax.broadcasted_iota(
+        jnp.int32, (page, width), 0)
+    spread = jax.lax.dot_general(
+        ok.astype(jnp.float32), tile.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n, width), 1)
+    own = lane // (page * hkv) == jax.lax.broadcasted_iota(
+        jnp.int32, (n, width), 0)
+    return jnp.sum(jnp.where(own, spread, 0.0), axis=0, keepdims=True) > 0.5
 
-    @pl.when(lane_live)
-    def _accumulate():
-        # A page slot is real only if the position actually written there is
-        # the absolute position this table slot stands for — a page recycled
-        # from a freed lane, or written only up to mid-page, fails this.
-        kpos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        mask = (kpos < cache_len) & (pos_ref[0] == kpos)
+
+def _accumulate_block(q_ref, k_blk, v_blk, mask, m_scr, l_scr, acc_scr, *,
+                      scale, softcap, hkv):
+    """One online-softmax update of every q head over a gathered
+    ``(blk, Hkv, D)`` VMEM block, as two matmuls for all heads at once:
+    the block read as ``(blk * Hkv, D)`` rows (a contiguous load, where
+    one head's rows would be a strided one), and each q head's scores
+    masked to its own KV head's rows.  ``q . k`` takes the cache's own
+    operands (bf16 products are exact in f32) with f32 accumulation;
+    softmax statistics, ``p`` and the accumulator stay f32."""
+    blk, _, d = k_blk.shape
+    hq = q_ref.shape[1]
+    k = k_blk[...].reshape(blk * hkv, d)               # row t * hkv + h
+    q = q_ref[0]                                       # (hq, d)
+    if q.dtype != k.dtype:
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    q_head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // (hq // hkv)
+    kv_head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) % hkv
+    mask = mask & (q_head == kv_head)
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_scr[...]                                # (hq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    v = v_blk[...].reshape(blk * hkv, d).astype(jnp.float32)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, pos_hbm,
+                         o_ref, k_buf, v_buf, pos_buf, sems, slot_ref,
+                         m_scr, l_scr, acc_scr,
+                         *, scale, window, softcap, page, max_pages, bp, hkv):
+    lane = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    blk = bp * page
+
+    def live_pages(b_):
+        """``[lo, hi)``: the table slots of lane ``b_`` that hold a position
+        below its length and inside the window."""
+        n = len_ref[b_]
+        hi = jnp.minimum((n + page - 1) // page, max_pages)
+        lo = jnp.maximum(n - window, 0) // page if window > 0 else 0
+        return lo, jnp.maximum(hi, lo)
+
+    def live_blocks(b_):
+        lo, hi = live_pages(b_)
+        return lo // bp, (hi + bp - 1) // bp
+
+    def block_pages(b_, ib, slot, fn):
+        """``fn(j, row, copies)`` for each live table slot ``j`` of block
+        ``ib``: its pool row (-1 if absent) and its K, V and position DMAs
+        into slot ``j`` of buffer ``slot``."""
+        lo, hi = live_pages(b_)
+
+        def one(j, carry):
+            row = tbl_ref[b_ * max_pages + ib * bp + j]
+            src = jnp.maximum(row, 0)
+            dst = pl.ds(j * page, page)
+            fn(j, row, (
+                pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, dst],
+                                      sems.at[1, slot]),
+                pltpu.make_async_copy(pos_hbm.at[src], pos_buf.at[slot, j],
+                                      sems.at[2, slot])))
+            return carry
+
+        jax.lax.fori_loop(jnp.clip(lo - ib * bp, 0, bp),
+                          jnp.clip(hi - ib * bp, 0, bp), one, 0)
+
+    def start(b_, ib, slot):
+        def go(j, row, copies):
+            @pl.when(row >= 0)
+            def _():
+                for c in copies:
+                    c.start()
+        block_pages(b_, ib, slot, go)
+
+    def wait(b_, ib, slot):
+        def done(j, row, copies):
+            @pl.when(row >= 0)
+            def _():
+                for c in copies:
+                    c.wait()
+
+            @pl.when(row < 0)
+            def _():
+                # An absent slot keeps stale positions that could match.
+                pos_buf[slot, j] = jnp.full(pos_buf.shape[2:], -1, jnp.int32)
+        block_pages(b_, ib, slot, done)
+
+    first, end = live_blocks(lane)
+    nxt = jnp.minimum(lane + 1, lanes - 1)
+    nxt_first, nxt_end = live_blocks(nxt)
+    nxt_live = (lane + 1 < lanes) & (nxt_first < nxt_end)
+
+    @pl.when(lane == 0)
+    def _first_lane():
+        # Slots a block does not copy keep an earlier block's data (or, at
+        # first, whatever VMEM held): their p is 0, and 0 x V must not be
+        # NaN.  Every later lane finds its first block already in flight,
+        # issued by the lane before.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+
+        @pl.when(first < end)
+        def _():
+            start(lane, first, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    n = len_ref[lane]
+    slot0 = slot_ref[0]
+    kpos0 = (jax.lax.broadcasted_iota(jnp.int32, (bp, page), 0) * page
+             + jax.lax.broadcasted_iota(jnp.int32, (bp, page), 1))
+
+    def body(ib, carry):
+        slot = jax.lax.rem(slot0 + ib - first, 2)
+        # The next block's DMAs go out before this block's compute: this
+        # lane's next block, or after its last the next lane's first.
+        more = ib + 1 < end
+
+        @pl.when(more | nxt_live)
+        def _():
+            start(jnp.where(more, lane, nxt), jnp.where(more, ib + 1, nxt_first),
+                  1 - slot)
+
+        wait(lane, ib, slot)
+        # A slot counts only if the position written there is the absolute
+        # position this table slot stands for: a page recycled from a freed
+        # lane, or written only up to mid-page, fails this.
+        kpos = ib * blk + kpos0
+        ok = (pos_buf[slot, :, 0, :page] == kpos) & (kpos < n)
         if window > 0:
-            mask &= kpos > cache_len - 1 - window
-        _accumulate_heads(q_ref, k_ref, v_ref, mask, m_scr, l_scr, acc_scr,
+            ok &= kpos > n - 1 - window
+        _accumulate_block(q_ref, k_buf.at[slot], v_buf.at[slot],
+                          _spread_slots(ok, page, hkv), m_scr, l_scr, acc_scr,
                           scale=scale, softcap=softcap, hkv=hkv)
+        return carry
 
-    @pl.when(pi == max_pages - 1)
-    def _done():
-        _finish(o_ref, l_scr, acc_scr, hkv)
+    jax.lax.fori_loop(first, end, body, 0)
+
+    @pl.when((first == end) & nxt_live)
+    def _empty_lane():
+        start(nxt, nxt_first, slot0)
+
+    slot_ref[0] = jax.lax.rem(slot0 + end - first, 2)
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+        o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, pos_pages, tables, *,
@@ -220,63 +387,65 @@ def paged_decode_attention(q, k_pages, v_pages, pos_pages, tables, *,
     tables:    (B, max_pages) int32 page ids, -1 = absent
     cache_len: scalar or (B,) per-lane lengths.
 
-    Same streaming grid as ``decode_attention`` with the KV-block axis
-    replaced by the table-slot axis: the block table and per-lane lengths
-    ride in scalar prefetch (``PrefetchScalarGridSpec``) so each KV tile's
-    BlockSpec index map *dereferences the table* — the pipeline DMAs
-    exactly the pages the lane owns, in position order, and non-contiguous
-    pool rows cost nothing extra.  The page tile (all KV heads of one
-    page) doubles as the flash block; masking re-checks the gathered
-    ``pos`` so a recycled page never leaks a previous tenant's keys.
+    The pools stay in HBM and the kernel issues its own DMAs.  The grid is
+    one step per lane; the block table and lengths ride in scalar prefetch.
+    A lane walks only its live blocks, ``[first, ceil(len / blk))`` with
+    ``first`` the block where a sliding window starts, in a ``fori_loop``.
+    A block is ``blk = bp * page`` tokens, ``bp = BLOCK_TOKENS // page``
+    (capped by the table): per table slot below the lane's length with a
+    page mapped, one DMA each of the page's K, V and position rows into
+    slot ``j`` of a double-buffered VMEM block.  The next block's copies
+    (after a lane's last block, the next lane's first) are issued before
+    the current block is computed.  Absent slots, slots past the length or
+    before the window, and lanes of length 0 issue no DMA and take no grid
+    step: the step count is the lanes, the DMA count the live pages.
+    Masking re-checks each gathered position against the slot's absolute
+    position, so a recycled page never leaks a previous tenant's keys.
     """
     b, _, hq, d = q.shape
     num_rows, page, hkv = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
-    rep = hq // hkv
     max_pages = tables.shape[1]
+    bp = max(1, min(BLOCK_TOKENS // page, max_pages))     # pages a block
     scale = scale if scale is not None else d ** -0.5
 
-    qg = q[:, 0].reshape(b, hkv, rep, d)
     lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
     tbl = tables.reshape(-1).astype(jnp.int32)          # (B * max_pages,)
-    pos3 = pos_pages.reshape(num_rows, 1, page)
+    # One DMA moves whole rows of the (1, 128) tiles a position row lives in.
+    width = -(-page // 128) * 128
+    pos3 = jnp.pad(pos_pages.astype(jnp.int32), ((0, 0), (0, width - page)),
+                   constant_values=-1).reshape(num_rows, 1, width)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window, softcap=softcap,
-        page=page, max_pages=max_pages, hkv=hkv)
-
-    def _page_row(b_, pi, tbl_ref):
-        # Clamp absent (-1) slots to row 0: the tile is skipped in-kernel.
-        return jnp.maximum(tbl_ref[b_ * max_pages + pi], 0)
-
+        page=page, max_pages=max_pages, bp=bp, hkv=hkv)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    # q head i reads KV head i // (Hq / Hkv): (B, Hq, D) rows in that order
+    lane_block = pl.BlockSpec((1, hq, d), lambda b_, *_: (b_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, hkv, rep, d),
-                         lambda b_, pi, tbl_ref, len_ref: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, d),
-                         lambda b_, pi, tbl_ref, len_ref:
-                         (_page_row(b_, pi, tbl_ref), 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, d),
-                         lambda b_, pi, tbl_ref, len_ref:
-                         (_page_row(b_, pi, tbl_ref), 0, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda b_, pi, tbl_ref, len_ref:
-                         (_page_row(b_, pi, tbl_ref), 0, 0)),
+        grid=(b,),
+        in_specs=[lane_block, hbm, hbm, hbm],
+        out_specs=lane_block,
+        scratch_shapes=[
+            pltpu.VMEM((2, bp * page, hkv, d), k_pages.dtype),
+            pltpu.VMEM((2, bp * page, hkv, d), v_pages.dtype),
+            pltpu.VMEM((2, bp, 1, width), jnp.int32),
+            pltpu.SemaphoreType.DMA((3, 2)),
+            pltpu.SMEM((1,), jnp.int32),               # slot of the next block
+            pltpu.VMEM((hq, 1), jnp.float32),          # running max
+            pltpu.VMEM((hq, 1), jnp.float32),          # running sum
+            pltpu.VMEM((hq, d), jnp.float32),          # output accumulator
         ],
-        out_specs=pl.BlockSpec((1, hkv, rep, d),
-                               lambda b_, pi, tbl_ref, len_ref:
-                               (b_, 0, 0, 0)),
-        scratch_shapes=_scratch(hkv, rep, d),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # lanes run in order: each prefetches the next one's first block
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(tbl, lens, qg, k_pages, v_pages, pos3)
+    )(tbl, lens, q[:, 0], k_pages, v_pages, pos3)
     return out.reshape(b, 1, hq, d)

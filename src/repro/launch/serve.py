@@ -139,11 +139,15 @@ def loop_report(results, replicas) -> List[str]:
                 for r in mine if r.predicted_ms > 0.0 and r.latency_ms() > 0.0]
         c = rep.counters
         share = 100.0 * c.prefill_ms / c.loop_ms if c.loop_ms > 0.0 else 0.0
+        # the decode kernel reads live context only: of every KV slot its
+        # steps could address (lanes x capacity), the share it read
+        slots = c.decode_steps * rep.slots * rep.capacity
+        read = f"{100.0 * c.context_tokens / slots:.1f}%" if slots else "n/a"
         lines.append(
             f"{rep.name}: queue wait p50={_pct(waits, 50, 'ms')} "
             f"p90={_pct(waits, 90, 'ms')} prefill share={share:.1f}% "
             f"predictor error p50={_pct(errs, 50, '%')} "
-            f"p90={_pct(errs, 90, '%')} "
+            f"p90={_pct(errs, 90, '%')} KV slots read={read} "
             f"({c.turns} turns, {c.decode_steps} decode steps, "
             f"{c.decode_tokens} tokens, {c.prefill_chunks} prefill chunks)")
     return lines

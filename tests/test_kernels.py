@@ -178,20 +178,46 @@ PAGED_CASES = [
     (2, 16, 4, 4, 1, 16, (17, 64), 24, jnp.float32),
     (2, 8, 8, 8, 2, 32, (30, 61), 0, jnp.bfloat16),
 ]
+# The same, plus softcap and ``reserve``: table slots mapped past each
+# lane's length to pages holding exactly the positions those slots stand
+# for (another lane's writes), which only the length may mask.  With 256
+# tokens a block (``BLOCK_TOKENS``), these cross block edges.
+PAGED_BLOCK_CASES = [
+    # b, page, maxp, hq, hkv, d, lens, window, dtype, softcap, reserve
+    # a lane of length 0, mid-page, on a block edge, one past it, the
+    # whole table; 40 pages = 2.5 blocks of 16
+    (5, 16, 40, 8, 2, 32, (0, 100, 256, 257, 640), 0, jnp.float32, 0.0, 3),
+    # window and softcap; 48 pages = 1.5 blocks of 32
+    (3, 8, 48, 4, 1, 32, (300, 383, 1), 100, jnp.float32, 30.0, 2),
+    # 12 pages = 1.5 blocks of 8
+    (4, 32, 12, 8, 2, 32, (256, 257, 0, 384), 0, jnp.bfloat16, 0.0, 1),
+    # the served model's heads (qwen3-4b: 32 q, 8 kv, 128 wide), bf16
+    (3, 16, 20, 32, 8, 128, (0, 200, 320), 0, jnp.bfloat16, 0.0, 2),
+]
+_PAGED_PARAMS = (
+    # no softcap, no reserve; ids as pytest names the nine fields
+    [pytest.param(*c, 0.0, 0, id="-".join(
+        [str(x) for x in c[:6]] + [f"lens{i}", str(c[7]), c[8].__name__]))
+     for i, c in enumerate(PAGED_CASES)]
+    + [pytest.param(*c, id=f"blocks-page{c[1]}-maxp{c[2]}-{i}")
+       for i, c in enumerate(PAGED_BLOCK_CASES)])
 
 
-@pytest.mark.parametrize("b,page,maxp,hq,hkv,d,lens,window,dtype",
-                         PAGED_CASES)
+@pytest.mark.parametrize(
+    "b,page,maxp,hq,hkv,d,lens,window,dtype,softcap,reserve", _PAGED_PARAMS)
 def test_paged_decode_attention_vs_oracle(b, page, maxp, hq, hkv, d, lens,
-                                          window, dtype):
+                                          window, dtype, softcap, reserve):
     """Block-table paged decode: shuffled pool rows, absent table slots and
     a recycled page whose written positions do not match must all agree
-    with ``ref.paged_decode_mha_reference``."""
+    with ``ref.paged_decode_mha_reference``.  A lane of length 0 (its
+    table all -1) attends to nothing and reads zeros."""
     rng = np.random.default_rng(0)
     rows = b * maxp + 1                          # + the dump row
     perm = rng.permutation(rows - 1).astype(np.int32).reshape(b, maxp)
     lens_a = np.asarray(lens, np.int32)
-    tables = np.where(np.arange(maxp)[None] * page < lens_a[:, None], perm, -1)
+    mapped = -(-lens_a[:, None] // page) + reserve
+    tables = np.where(np.arange(maxp)[None] < mapped, perm, -1)
+    tables[lens_a == 0] = -1
     pos = np.full((rows, page), -1, np.int32)
     for lane in range(b):
         for j in range(maxp):
@@ -199,18 +225,26 @@ def test_paged_decode_attention_vs_oracle(b, page, maxp, hq, hkv, d, lens,
                 pos[tables[lane, j]] = j * page + np.arange(page)
     # a previous tenant's page under the longest lane's first table slot
     pos[perm[int(np.argmax(lens_a)), 0]] += 3
+    if reserve:
+        # and an absent slot below its length: the page it named keeps
+        # positions that match, so only the table may mask it
+        tables[int(np.argmax(lens_a)), 2] = -1
     k1, k2, k3 = jax.random.split(KEY, 3)
     q = rand(k1, (b, 1, hq, d), dtype)
     kp = rand(k2, (rows, page, hkv, d), dtype)
     vp = rand(k3, (rows, page, hkv, d), dtype)
     args = (q, kp, vp, jnp.asarray(pos), jnp.asarray(tables))
     out = paged_decode_attention(*args, cache_len=jnp.asarray(lens_a),
-                                 window=window, interpret=True)
+                                 window=window, softcap=softcap,
+                                 interpret=True)
     exp = ref.paged_decode_mha_reference(*args, cache_len=jnp.asarray(lens_a),
-                                         window=window)
+                                         window=window, softcap=softcap)
+    live = lens_a > 0
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32), atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(exp, np.float32)[live],
+                               atol=tol, rtol=tol)
+    assert not np.asarray(out, np.float32)[~live].any()
 
 
 # ------------------------------------------------------------------------ SSD

@@ -48,16 +48,21 @@ def test_loop_report_reads_stamps_and_counters():
 
     results = [served(0, 2.0, 50.0), served(1, 4.0, 150.0),
                served(2, 9.0, 0.0, replica="replica1")]
-    reps = [SimpleNamespace(name="replica0", counters=LoopCounters(
-        turns=8, decode_steps=6, decode_tokens=9, prefill_chunks=2,
-        loop_ms=400.0, prefill_ms=100.0)),
-        SimpleNamespace(name="replica2", counters=LoopCounters())]
+    reps = [SimpleNamespace(name="replica0", slots=2, capacity=64,
+                            counters=LoopCounters(
+        turns=8, decode_steps=6, decode_tokens=9, context_tokens=192,
+        prefill_chunks=2, loop_ms=400.0, prefill_ms=100.0)),
+        SimpleNamespace(name="replica2", slots=2, capacity=64,
+                        counters=LoopCounters())]
     first, idle = loop_report(results, reps)
-    # waits 2 and 4 ms; |100 - 50| / 100 and |100 - 150| / 100
+    # waits 2 and 4 ms; |100 - 50| / 100 and |100 - 150| / 100;
+    # 192 context tokens of 6 steps x 2 lanes x 64 slots
     assert first == ("replica0: queue wait p50=3.0ms p90=3.8ms prefill "
                      "share=25.0% predictor error p50=50.0% p90=50.0% "
+                     "KV slots read=25.0% "
                      "(8 turns, 6 decode steps, 9 tokens, 2 prefill chunks)")
     assert "queue wait p50=n/a" in idle and "share=0.0%" in idle
+    assert "KV slots read=n/a" in idle
 
 
 def test_init_model_stacks_layers_as_per_layer_draws():

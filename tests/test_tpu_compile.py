@@ -70,15 +70,24 @@ def test_ring_decode_attention_compiles_for_v5e(one_chip, capacity):
         ((slots,), I32))
 
 
-def test_paged_decode_attention_compiles_for_v5e(one_chip):
-    slots, page, capacity = 8, 16, 1024
+def _compile_paged(sharding, slots, capacity, rows, page=16):
     maxp = capacity // page
-    rows = slots * maxp + 1
     _compile(lambda q, k, v, p, t, n: dk.paged_decode_attention(
-        q, k, v, p, t, cache_len=n), one_chip,
+        q, k, v, p, t, cache_len=n), sharding,
         ((slots, 1, H, D), BF), ((rows, page, HKV, D), BF),
         ((rows, page, HKV, D), BF), ((rows, page), I32),
         ((slots, maxp), I32), ((slots,), I32))
+
+
+def test_paged_decode_attention_compiles_for_v5e(one_chip):
+    slots, capacity = 8, 1024
+    _compile_paged(one_chip, slots, capacity, slots * capacity // 16 + 1)
+
+
+def test_paged_decode_attention_compiles_for_v5e_served_shapes(one_chip):
+    """The served qwen3-4b replica: 16 lanes of capacity 4096 (256 table
+    slots of 16 tokens) over a pool of 1,200 pages and the dump row."""
+    _compile_paged(one_chip, 16, 4096, 1201)
 
 
 def test_ssd_compiles_for_v5e(one_chip):
