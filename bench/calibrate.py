@@ -55,8 +55,7 @@ def main():
     rows = []
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.monotonic()
-        fl = harness.build_fleet(config, seed, devices[:cell["chips"]])
-        harness.warm_up(fl, spec, seed + 1, dims["vocab_size"])
+        fl = harness.build_fleet(config, seed, devices[:cell["chips"]], spec)
         run = harness.Run(config, dims, ref.FAMILY, {}, (0.0, 0.0))
         harness.drive(fl, spec, seed, dims["vocab_size"], args.seconds, run,
                       None)

@@ -1,8 +1,9 @@
 """``correct``: what the timed path served, against the plain reference.
 
 Once the window has closed, a sample of the finished requests drawn from
-the seed — the longest of them first, then others until the sample holds
-the configured number of served tokens — is run through the
+the seed — the longest of them first, then one from each replica not yet
+in it, then others until the sample holds the configured number of served
+tokens — is run through the
 configuration's float32 reference, prompt and served tokens together.  At
 each served position the reference's best logit is compared with its logit
 for the token the program served; the number compared is the widest such
@@ -31,8 +32,10 @@ def _roundup(n: int, m: int) -> int:
 def sample(records: List[Dict], seed: int, tokens: int,
            max_requests: int) -> List[Dict]:
     """Finished requests to compare: the longest (prompt + served tokens),
-    then others in an order drawn from ``seed``, until ``tokens`` served
-    tokens or ``max_requests`` requests."""
+    then, in an order drawn from ``seed``, one from each replica that has
+    none in the sample yet, then others until ``tokens`` served tokens or
+    ``max_requests`` requests.  Every replica that finished a request is
+    compared, whatever the limits."""
     ok = [r for r in records if r["ok"]]
     if not ok:
         return []
@@ -40,9 +43,17 @@ def sample(records: List[Dict], seed: int, tokens: int,
     rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 7])
     rest = [ok[i] for i in 1 + rng.permutation(len(ok) - 1)]
     out, served = [ok[0]], ok[0]["n_out"]
+    seen = {ok[0].get("replica")}
+    for r in rest:
+        if r.get("replica") not in seen:
+            seen.add(r.get("replica"))
+            out.append(r)
+            served += r["n_out"]
     for r in rest:
         if served >= tokens or len(out) >= max_requests:
             break
+        if any(r is o for o in out):
+            continue
         out.append(r)
         served += r["n_out"]
     return out

@@ -130,10 +130,15 @@ def enable_compile_cache() -> str:
     return path
 
 
-def build_fleet(config: Dict, seed: int, devices: List) -> Fleet:
+def build_fleet(config: Dict, seed: int, devices: List,
+                spec: Optional[Dict] = None) -> Fleet:
     """Weights from the seed on the first device (one jitted call, in the
     served dtype), a copy on each further device, one warm replica per
-    device, profiled, behind one DDS ``ServingFleet``."""
+    device, profiled, behind one DDS ``ServingFleet``.  With ``spec`` (a
+    traffic mix) every replica is warmed up on it before it joins the
+    fleet: a cold checkout compiles during the warm-up, and a compile
+    holds a replica's decode loop for longer than the fleet's hang
+    detector allows a replica with work in hand."""
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config
@@ -146,11 +151,13 @@ def build_fleet(config: Dict, seed: int, devices: List) -> Fleet:
         dtype=dtype, param_dtype=dtype, **dims)
     ref = reference_of(config)
     n = config["fleet"]["replicas"]
+    t0 = time.monotonic()
     init = jax.jit(lambda k: ref.init_params(k, dims, dtype),
                    out_shardings=jax.sharding.SingleDeviceSharding(devices[0]))
     first = init(seed_key(seed))
     params = [first] + [jax.device_put(first, d) for d in devices[1:n]]
     jax.block_until_ready(params)
+    t1 = time.monotonic()
 
     def make(i):
         rep = Replica(f"replica{i}", cfg, params[i], **config["replica"])
@@ -161,13 +168,19 @@ def build_fleet(config: Dict, seed: int, devices: List) -> Fleet:
     else:
         with ThreadPoolExecutor(max_workers=n) as ex:
             built = list(ex.map(make, range(n)))
+    replicas = [r for r, _ in built]
+    t2 = time.monotonic()
+    if spec is not None:
+        warm_up(replicas, spec, seed + 1, dims["vocab_size"])
+    say(f"set-up: weights {t1 - t0:.3f} s, replicas built and profiled "
+        f"{t2 - t1:.3f} s, warm-up {time.monotonic() - t2:.3f} s")
     f = config["fleet"]
     fleet = ServingFleet(make_policy(f["policy"]), source=f["source"],
                          coordinator=f["coordinator"],
                          admission_margin=f["admission_margin"])
     for rep, prof in built:
         fleet.add_replica(rep, profile=prof)
-    return Fleet(fleet, [r for r, _ in built], params, devices[:n])
+    return Fleet(fleet, replicas, params, devices[:n])
 
 
 # ------------------------------------------------------------------ driving
@@ -218,9 +231,9 @@ def _submit(fleet, p: generator.Planned, due_ms: float):
     return _record(p, due_ms, fleet.submit(req), sent)
 
 
-def warm_up(fl: Fleet, spec: Dict, seed: int, vocab: int) -> None:
+def warm_up(replicas: List, spec: Dict, seed: int, vocab: int) -> None:
     """Serve a few requests of the cell's own mix, four tokens each, on
-    every replica (straight to the replica, not through the router, which
+    every replica (straight to the replica, not through a router, which
     need not reach them all): first one alone, then the rest at once, so
     that every shape the window uses (prefix restore, commit, lane insert,
     first-token selection) has run on every device before the clock
@@ -235,7 +248,7 @@ def warm_up(fl: Fleet, spec: Dict, seed: int, vocab: int) -> None:
     rng = np.random.default_rng(generator.seed_words(seed))
     widths = {rep.name: rng.integers(
         generator.FIRST_TOKEN, vocab, size=2 * rep.prefill_chunk_tokens - 1
-    ).astype(np.int32) for rep in fl.replicas}
+    ).astype(np.int32) for rep in replicas}
 
     def serve(rep):
         # deadlines no predictor can shed: the warm-up must run whole
@@ -249,8 +262,8 @@ def warm_up(fl: Fleet, spec: Dict, seed: int, vocab: int) -> None:
         if any(len(o) != r.max_new_tokens for o, r in zip(outs, reqs[1:])):
             raise RuntimeError(f"warm-up on {rep.name} came back short")
 
-    with ThreadPoolExecutor(max_workers=len(fl.replicas)) as ex:
-        list(ex.map(serve, fl.replicas))
+    with ThreadPoolExecutor(max_workers=len(replicas)) as ex:
+        list(ex.map(serve, replicas))
 
 
 class Marks(threading.Thread):
@@ -439,8 +452,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     dims = dims_of(config)
     vocab = dims["vocab_size"]
     ref = reference_of(config)
-    fl = build_fleet(config, seed, devices)
-    warm_up(fl, spec, seed + 1, vocab)
+    fl = build_fleet(config, seed, devices, spec)
     setup_s = time.monotonic() - t_start
     say(f"set-up {setup_s:.3f} s")
 

@@ -1,8 +1,9 @@
 """Model step, whole: model FLOPs of the tokens decoded and prefilled in
-the traced window over the device's busy time in it times the chip's bf16
-peak.  Decode tokens count their attention over the live context; prompt
-tokens count their weights only (their attention over earlier context is
-left out, so the share is a lower bound)."""
+the traced window, on every chip of the fleet, over the chips' busy time
+in it (busy seconds per chip times chips) times one chip's bf16 peak.
+Decode tokens count their attention over the live context; prompt tokens
+count their weights only (their attention over earlier context is left
+out, so the share is a lower bound)."""
 from bench import counts
 from bench.window import decode_contexts, prefilled
 
@@ -17,4 +18,5 @@ def read(name, run):
     flops += pre * counts.token_flops(run.dims, run.family, 0, False)
     if not flops:
         return None
-    return 100.0 * flops / (run.trace.busy_s * run.peaks["bf16_flops_per_s"])
+    chip_s = run.trace.busy_s * run.trace.devices
+    return 100.0 * flops / (chip_s * run.peaks["bf16_flops_per_s"])

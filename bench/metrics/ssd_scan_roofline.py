@@ -1,7 +1,9 @@
 """Kernel ``ssd_scan``: the least time the chip needs for the SSD scan of
 the prompt tokens prefilled in the traced window — the recurrence's
 operations and its inputs and outputs, at the larger of the FLOP and HBM
-bounds (bytes, at these shapes) — over the kernel's summed device time."""
+bounds (bytes, at these shapes) — over the kernel's device time summed
+over every chip that prefilled them (the trace gives it per chip,
+averaged)."""
 from bench import counts
 from bench.window import prefilled
 
@@ -11,7 +13,7 @@ KERNEL = r"ssd_scan"
 def read(name, run):
     if run.trace is None or run.family != "ssm":
         return None
-    kernel_s = run.trace.seconds_of(KERNEL)
+    kernel_s = run.trace.seconds_of(KERNEL) * run.trace.devices
     tokens = prefilled(run, "trace_start", "trace_end")
     if kernel_s <= 0 or not tokens:
         return None

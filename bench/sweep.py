@@ -83,8 +83,7 @@ def main():
         lim["tpot_ms"] = args.tpot_ms
     spec["limits"] = lim
     dims = harness.dims_of(config)
-    fl = harness.build_fleet(config, args.seed, devices[:cell["chips"]])
-    harness.warm_up(fl, spec, args.seed + 1, dims["vocab_size"])
+    fl = harness.build_fleet(config, args.seed, devices[:cell["chips"]], spec)
     harness.say(f"set-up {time.monotonic() - T_START:.1f} s")
     rows = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):

@@ -9,8 +9,8 @@ def bench():
     return harness.load_json(harness.ROOT / "BENCHMARK.json")
 
 
-def small_qwen():
-    c = harness.load_json(harness.BENCH / "configs" / "qwen3-4b.json")
+def small_qwen(name="qwen3-4b"):
+    c = harness.load_json(harness.BENCH / "configs" / f"{name}.json")
     c["model"].update(hidden_size=64, intermediate_size=128,
                       num_hidden_layers=2, num_attention_heads=4,
                       num_key_value_heads=2, head_dim=16, vocab_size=256)
@@ -27,8 +27,8 @@ def small_mamba():
     return c
 
 
-def small_sessions():
-    s = harness.load_json(harness.BENCH / "traffic" / "sessions.json")
+def small_sessions(mix="sessions"):
+    s = harness.load_json(harness.BENCH / "traffic" / f"{mix}.json")
     s = copy.deepcopy(s)
     s.update(system_prompt_tokens=64, max_prompt_tokens=192,
              session_rate_per_s=1.0, lead_in_s=1.0, drain_s=60.0)

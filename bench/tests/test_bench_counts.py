@@ -100,6 +100,53 @@ def test_roofline_and_mfu_readers_stay_under_100():
         pytest.approx(50.0)
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_device_time_readers_count_every_chip(chips):
+    """The trace averages device time over the chips; the work of the
+    tokens counted is the whole fleet's.  The readers that divide one by
+    the other count every chip's seconds: on one chip they read what they
+    always did, on four a quarter of it.  The rest are ratios of two
+    averages or means per call, the same on any number of chips."""
+    ops = {"paged_decode_attention": 0.01, "fusion": 0.2, "ssd_scan": 0.02}
+    red = trace_reduce.Reduced(1.0, 0.5, chips, ops,
+                               {"jit__step_paged_impl": 0.3,
+                                "jit_prefill_chunk": 0.05},
+                               {"jit__step_paged_impl": 10.0,
+                                "jit_prefill_chunk": 2.0}, [])
+    counters = {"trace_start": {"prefilled": {"r": 0}, "placed": {}},
+                "trace_end": {"prefilled": {"r": 64}, "placed": {}}}
+    run = _run(red, _records(), counters)
+    out = harness.per_layer(["paged_decode_attention_roofline.tpot",
+                             "mfu.tpot", "decode_step_ms.tpot",
+                             "idle_share.tpot", "prefill_device_share.tpot"],
+                            run)
+    ctxs = list(range(21, 31))
+    c = counts.paged_attention_counts(DENSE, ctxs)
+    flops = sum(counts.dense_token_flops(DENSE, x, True) for x in ctxs) + \
+        64 * counts.dense_token_flops(DENSE, 0, False)
+    # one chip's readings, as these readers took them before they counted
+    # chips
+    roof = 100.0 * max(c["flops"] / 1e12, c["bytes"] / 1e9) / 0.01
+    mfu = 100.0 * flops / (0.5 * 1e12)
+    if chips == 1:
+        assert out["paged_decode_attention_roofline.tpot"] == roof
+        assert out["mfu.tpot"] == mfu
+    else:
+        assert out["paged_decode_attention_roofline.tpot"] == \
+            pytest.approx(roof / chips)
+        assert out["mfu.tpot"] == pytest.approx(mfu / chips)
+    assert out["decode_step_ms.tpot"] == pytest.approx(30.0)
+    assert out["idle_share.tpot"] == pytest.approx(50.0)
+    assert out["prefill_device_share.tpot"] == pytest.approx(10.0)
+
+    ssm = _run(red, [], counters)
+    ssm.family, ssm.dims = "ssm", SSM
+    sc = counts.ssd_scan_counts(SSM, 64)
+    scan = 100.0 * max(sc["flops"] / 1e12, sc["bytes"] / 1e9) / 0.02
+    got = harness.per_layer(["ssd_scan_roofline.tput"], ssm)
+    assert got["ssd_scan_roofline.tput"] == pytest.approx(scan / chips)
+
+
 def test_readers_with_nothing_to_read_return_nothing():
     red = trace_reduce.Reduced(1.0, 0.5, 1, {"fusion": 0.2}, {}, {}, [])
     run = _run(red, [], {})
